@@ -48,10 +48,10 @@ def _make_engine(cfg, params, batch, spec, sync):
     # planner_threads=1: on small hosts a single planner hides behind device
     # execution without GIL-fighting a second builder; depth=2 = double
     # buffering (wave k+1 plans while k executes and k-1 drains).
-    # use_kernel=True serves the SSpNNA tiled path — device work is pure XLA
-    # (GIL-free), which is what the host plan pass overlaps against.
-    return SceneEngine(cfg, params, batch=batch, spec=spec, use_kernel=True,
-                       sync=sync, depth=2, planner_threads=1)
+    # Levels the spec sends to "sspnna" run the fused Pallas kernel:
+    # compiled on a TPU, the Pallas interpreter on any other backend.
+    return SceneEngine(cfg, params, batch=batch, spec=spec, sync=sync,
+                       depth=2, planner_threads=1)
 
 
 def _serve(eng, scenes, base_rid, group=None):

@@ -6,9 +6,9 @@ Three arms over the same scene and parameters:
 * ``serial_SN``     — the deterministic sharded program on one device
   (``vmap(axis_name=...)``), the bitwise oracle for the mesh arm;
 * ``mesh_SN``       — the same program ``shard_map``-ed over an N-way mesh
-  axis with real halo-exchange/all-gather collectives (runs when the host
-  exposes >= N devices, e.g. under
-  ``XLA_FLAGS=--xla_force_host_platform_device_count=4``; the CI smoke job
+  axis with real halo-exchange/all-gather collectives. It needs >= N
+  devices and fails without them; on a CPU host run under
+  ``XLA_FLAGS=--xla_force_host_platform_device_count=4`` (the CI smoke job
   sets exactly that).
 
 The interesting number on CPU hosts is not wall-clock (virtual devices
@@ -16,8 +16,8 @@ share the same cores; the sharded arms also pay the deterministic
 plane-accumulated contraction) but the *wire traffic model* in the derived
 column: ``halo_kb`` is what the plan's send tables actually exchange per
 forward (plus the chunked BN partial gathers), ``dense_kb`` what a naive
-replicated all-gather of every conv input would move. The bitwise
-serial==mesh assertion runs whenever both arms do.
+replicated all-gather of every conv input would move. The mesh arm is
+asserted bitwise equal to the serial arm.
 """
 from __future__ import annotations
 
@@ -97,23 +97,21 @@ def run(quick: bool = False):
     us = common.time_fn(serial_fn, params, t.feats, iters=3, reps=2)
     common.emit(f"sharded_scene/serial_S{n_shards}", us, traffic)
 
-    if len(jax.devices()) >= n_shards:
-        mesh = make_mesh((n_shards,), ("shard",),
-                         devices=jax.devices()[:n_shards])
-        ctx = engine.ExecutionContext(mesh=mesh)
-        mesh_fn = jax.jit(lambda p, f: engine.apply_unet(p, f, splan,
-                                                         ctx=ctx))
-        us = common.time_fn(mesh_fn, params, t.feats, iters=3, reps=2)
-        # the mesh execution must be bitwise the serial oracle
-        same = np.array_equal(np.asarray(mesh_fn(params, t.feats)),
-                              np.asarray(serial_fn(params, t.feats)))
-        assert same, "mesh sharded forward diverged from the serial oracle"
-        common.emit(f"sharded_scene/mesh_S{n_shards}", us,
-                    f"bitwise_vs_serial=ok {traffic}")
-    else:
-        common.emit(f"sharded_scene/mesh_S{n_shards}", 0.0,
-                    f"skipped: {len(jax.devices())} device(s) < {n_shards} "
-                    "(set XLA_FLAGS=--xla_force_host_platform_device_count=4)")
+    if len(jax.devices()) < n_shards:
+        raise RuntimeError(
+            f"mesh_S{n_shards} needs {n_shards} devices, found "
+            f"{len(jax.devices())} (on CPU set "
+            "XLA_FLAGS=--xla_force_host_platform_device_count=4)")
+    mesh = make_mesh((n_shards,), ("shard",), devices=jax.devices()[:n_shards])
+    ctx = engine.ExecutionContext(mesh=mesh)
+    mesh_fn = jax.jit(lambda p, f: engine.apply_unet(p, f, splan, ctx=ctx))
+    us = common.time_fn(mesh_fn, params, t.feats, iters=3, reps=2)
+    # the mesh execution must be bitwise the serial oracle
+    same = np.array_equal(np.asarray(mesh_fn(params, t.feats)),
+                          np.asarray(serial_fn(params, t.feats)))
+    assert same, "mesh sharded forward diverged from the serial oracle"
+    common.emit(f"sharded_scene/mesh_S{n_shards}", us,
+                f"bitwise_vs_serial=ok {traffic}")
 
 
 def main(argv=None) -> None:

@@ -36,6 +36,9 @@ def main(argv=None) -> None:
                     help="also write rows as a JSON artifact (CI perf log)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+
     from benchmarks import (
         bench_admission,
         bench_coir,

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.data.tokens import TokenStream
+from repro.launch.compile_cache import use_compile_cache
 from repro.training import checkpoint
 from repro.training.optimizer import OptHParams
 from repro.training.train_loop import init_train_state, make_train_step
@@ -24,6 +25,7 @@ def main():
                     help="~100M params (slower on CPU)")
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    use_compile_cache()
 
     base = get_config("stablelm-1.6b")
     if args.full:  # ~100M params

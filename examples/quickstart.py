@@ -15,9 +15,11 @@ from repro.core import soar, spade
 from repro.core.hashgrid import build_neighbor_table, kernel_offsets
 from repro.core.sparse_conv import init_sparse_conv, submanifold_coir
 from repro.data.scenes import make_scene
+from repro.launch.compile_cache import use_compile_cache
 from repro.sparse.tensor import SparseVoxelTensor
 
 RES, CAP = 48, 16384
+use_compile_cache()
 
 coords, feats, labels, mask = make_scene(0, RES, CAP)
 t = SparseVoxelTensor(jnp.asarray(coords), jnp.asarray(feats), jnp.asarray(mask))

@@ -12,8 +12,7 @@ timings; ``--sync`` falls back to the blocking wave loop for comparison.
 splits over an N-way mesh axis, per-shard plans (local COIR + halo send
 tables) build on the planner threads, and every conv exchanges only its
 halo rows (run under ``XLA_FLAGS=--xla_force_host_platform_device_count=4``
-to get a real multi-device mesh on CPU; without enough devices the same
-program runs serially on one device — bitwise identical either way).
+to get a real multi-device mesh on CPU; with fewer than N devices it fails).
 
 Run:  PYTHONPATH=src python examples/segment_scene.py [--requests 8] [--sync]
 """
@@ -27,6 +26,7 @@ import numpy as np
 from repro import engine
 from repro.data.scenes import N_CLASSES, make_scene
 from repro.dist.compat import make_mesh
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.scn import UNetConfig, init_unet
 from repro.serving.scene_engine import SceneEngine, SceneRequest
 from repro.sparse.tensor import SparseVoxelTensor
@@ -53,6 +53,7 @@ def main():
                     help="serve mesh-sharded scenes over this many shards "
                          "(0 = unsharded batched serving)")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = UNetConfig(widths=(16, 32, 48), reps=1, resolution=args.res,
                      capacity=args.cap, n_classes=N_CLASSES)
@@ -64,13 +65,14 @@ def main():
         # pin the halo budget from representative scenes (one jit signature)
         layout = engine.pin_halo(
             reps, cfg, engine.ShardLayout(n_shards=args.shards))
-        mesh = None
-        if len(jax.devices()) >= args.shards:
-            mesh = make_mesh((args.shards,), ("shard",),
-                             devices=jax.devices()[:args.shards])
+        if len(jax.devices()) < args.shards:
+            raise SystemExit(
+                f"--shards {args.shards} needs {args.shards} devices, "
+                f"found {len(jax.devices())}")
+        mesh = make_mesh((args.shards,), ("shard",),
+                         devices=jax.devices()[:args.shards])
         ctx = engine.ExecutionContext(mesh=mesh)
-        print(f"sharded layout: {layout} on "
-              f"{'mesh' if mesh is not None else 'one device (serial)'}; "
+        print(f"sharded layout: {layout} on a {args.shards}-device mesh; "
               f"halo budget pinned in {time.time() - t0:.1f}s")
         eng = SceneEngine(cfg, params, batch=args.batch, ctx=ctx,
                           layout=layout, sync=args.sync, depth=args.depth,

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.data.scenes import N_CLASSES, make_lidar_sweep
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.scn import UNetConfig, init_unet
 from repro.serving.scene_engine import SceneEngine
 from repro.sparse.tensor import SparseVoxelTensor
@@ -32,6 +33,7 @@ def main():
     ap.add_argument("--sync", action="store_true",
                     help="blocking waves instead of the async pipeline")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = UNetConfig(widths=(16, 32, 32), reps=1, resolution=args.resolution,
                      capacity=args.capacity, n_classes=N_CLASSES)

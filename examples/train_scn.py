@@ -14,6 +14,7 @@ import numpy as np
 
 from repro import engine
 from repro.data.scenes import N_CLASSES, make_scene
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.scn import UNetConfig, init_unet, miou, segmentation_loss
 from repro.sparse.tensor import SparseVoxelTensor
 
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--cap", type=int, default=4096)
     ap.add_argument("--scenes", type=int, default=8)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = UNetConfig(widths=(16, 32, 48), reps=1, resolution=args.res,
                      capacity=args.cap, n_classes=N_CLASSES)

@@ -1,52 +1,22 @@
-"""Version shims for the jax sharding / shard_map API surface.
+"""The repo's spelling of the jax mesh / shard_map API.
 
-The repo targets the baked-in toolchain (jax 0.4.x) but must also lower on
-newer releases in CI. Three surfaces moved between versions:
-
-* ``jax.sharding.AxisType`` (auto/explicit sharding modes) appeared in 0.5+;
-  on older jax every mesh axis is implicitly "auto", so a stub enum suffices.
-* ``jax.make_mesh`` grew an ``axis_types`` kwarg alongside ``AxisType``.
-* ``shard_map`` graduated from ``jax.experimental`` and renamed its
-  ``check_rep`` kwarg to ``check_vma``.
+* ``make_mesh`` builds meshes whose axes are all ``AxisType.Auto``.
+* ``shard_map`` runs with replication checking (``check_vma``) off: the
+  sharded bodies here exchange rows by explicit collectives.
 """
 from __future__ import annotations
 
-import enum
-
 import jax
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
-
-    _HAS_AXIS_TYPE = True
-except ImportError:  # jax 0.4.x: every axis behaves as "auto"
-    class AxisType(enum.Enum):
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-    _HAS_AXIS_TYPE = False
-
-try:  # jax >= 0.6 exposes it at top level
-    from jax import shard_map as _shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with auto axis types on every jax version."""
-    if _HAS_AXIS_TYPE:
-        return jax.make_mesh(
-            axis_shapes, axis_names, devices=devices,
-            axis_types=(AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
+    """``jax.make_mesh`` with auto axis types."""
+    return jax.make_mesh(axis_shapes, axis_names, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, on every jax version."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    except TypeError:  # jax >= 0.6: check_rep renamed to check_vma
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
+    """``jax.shard_map`` with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

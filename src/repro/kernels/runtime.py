@@ -1,41 +1,26 @@
 """Shared Pallas execution-mode policy for every kernel wrapper.
 
 The kernels in this package are written for the TPU Pallas lowering; on any
-other backend they must run in interpret mode. Every wrapper used to
-hardcode ``interpret=True``, which silently pinned the interpreter even on
-real TPUs (ROADMAP "SSpNNA compiled path"). ``resolve_interpret`` is the
-single gate: an explicit ``True``/``False`` always wins, then the
-``REPRO_PALLAS_INTERPRET`` environment override, and the default
-(``None``) compiles on TPU and interprets everywhere else.
+other backend they must run in interpret mode. ``resolve_interpret`` is the
+single gate: an explicit ``True``/``False`` always wins, and the default
+(``None``) compiles on TPU and interprets everywhere else. Nothing in the
+environment can put a TPU into interpret mode.
 
-The public kernel wrappers resolve *before* their jit boundary, so a
-per-call env change retraces with the new mode. Long-lived jitted closures
-above them (``SceneEngine._apply``, the LM engine's prefill/step) capture
-the resolved mode at their own first trace — to change the mode of a
-running engine, pass ``interpret=`` explicitly when constructing it rather
-than flipping the env var afterwards.
+The public kernel wrappers resolve *before* their jit boundary, so the jit
+cache is keyed on the concrete mode. Long-lived jitted closures above them
+(``SceneEngine._apply``, the LM engine's prefill/step) capture the resolved
+mode at their own first trace — pass ``interpret=`` explicitly when
+constructing them to choose another.
 """
 from __future__ import annotations
 
-import os
-
 import jax
-
-ENV_INTERPRET = "REPRO_PALLAS_INTERPRET"
 
 
 def resolve_interpret(interpret: bool | None = None) -> bool:
-    """Resolve an ``interpret=`` knob to a concrete bool.
-
-    ``interpret`` of ``True``/``False`` is an explicit per-call override and
-    is returned as-is. ``None`` defers to the ``REPRO_PALLAS_INTERPRET``
-    env var (``0``/``false``/``off`` force compiled, anything truthy forces
-    interpret) and finally to backend presence: compiled on TPU, interpreted
-    on every other backend.
-    """
+    """Resolve an ``interpret=`` knob to a concrete bool: an explicit
+    ``True``/``False`` as-is, ``None`` compiled on TPU and interpreted on
+    every other backend."""
     if interpret is not None:
         return bool(interpret)
-    env = os.environ.get(ENV_INTERPRET)
-    if env is not None:
-        return env.strip().lower() not in ("0", "false", "no", "off", "")
     return jax.default_backend() != "tpu"

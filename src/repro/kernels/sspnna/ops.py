@@ -46,7 +46,6 @@ def run_sspnna_conv(
     fused: bool | None = None,
     interpret: bool | None = None,
     block_n: int | None = None,
-    block_k: int | None = None,
 ) -> jax.Array:
     """Tiled sparse convolution -> (n_out, N) features (no bias/mask).
 
@@ -71,13 +70,12 @@ def run_sspnna_conv(
     return _run_sspnna_conv(
         feats, weights, out_rows, in_rows, local_idx, pair_counts,
         n_out=n_out, use_kernel=use_kernel, fused=fused,
-        interpret=resolve_interpret(interpret), block_n=block_n,
-        block_k=block_k)
+        interpret=resolve_interpret(interpret), block_n=block_n)
 
 
 @functools.partial(
     jax.jit, static_argnames=("n_out", "use_kernel", "fused", "interpret",
-                              "block_n", "block_k"))
+                              "block_n"))
 def _run_sspnna_conv(
     feats: jax.Array,
     weights: jax.Array,
@@ -91,7 +89,6 @@ def _run_sspnna_conv(
     fused: bool,
     interpret: bool,
     block_n: int | None,
-    block_k: int | None,
 ) -> jax.Array:
     n = weights.shape[2]
     if fused:
@@ -99,16 +96,14 @@ def _run_sspnna_conv(
                   else (local_idx >= 0).sum(axis=(1, 2)).astype(jnp.int32))
         return sspnna_fused(
             feats, weights, out_rows, in_rows, local_idx, counts,
-            n_out=n_out, block_n=block_n, block_k=block_k,
-            interpret=interpret)
+            n_out=n_out, block_n=block_n, interpret=interpret)
     in_ok = in_rows >= 0
     tile_feats = jnp.take(feats, jnp.maximum(in_rows, 0), axis=0)
     tile_feats = jnp.where(in_ok[..., None], tile_feats, 0)
     if use_kernel:
         tile_out = sspnna_tiles(
             tile_feats, local_idx, weights, block_n=block_n,
-            block_k=block_k, interpret=interpret
-        )
+            interpret=interpret)
     else:
         tile_out = sspnna_tile_ref(tile_feats, local_idx, weights)
     rows = jnp.where(out_rows >= 0, out_rows, n_out)

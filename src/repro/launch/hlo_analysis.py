@@ -422,12 +422,6 @@ def analyze(text: str) -> HloCost:
 
 
 def xla_cost_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` normalized across jax versions.
-
-    jaxlib <= 0.4.x returns a one-element list of dicts (one per program);
-    newer versions return the dict directly. Either way, hand back a dict.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost) if cost else {}
+    """``Compiled.cost_analysis()`` as a plain dict (empty when XLA
+    reports nothing)."""
+    return dict(compiled.cost_analysis() or {})

@@ -187,6 +187,10 @@ class SceneEngine(ServingBase):
     (``depth`` device waves in flight, ``planner_threads`` host builders).
     ``sync`` / ``depth`` / ``planner_threads`` / ``policy`` default to the
     context's scheduler wiring when left ``None``.
+
+    Plans SPADE sends to ``"sspnna"`` run the fused Pallas kernel (compiled
+    on TPU, interpreted elsewhere); ``use_kernel=False`` swaps in its jnp
+    oracle, for CPU tests that need the speed.
     """
 
     def __init__(self, cfg, params, batch: int,
@@ -195,7 +199,7 @@ class SceneEngine(ServingBase):
                  layout: ShardLayout | None = None,
                  family: SignatureFamily | None = None,
                  policy: AdmissionPolicy | None = None,
-                 backend: str = "auto", use_kernel: bool = False,
+                 backend: str = "auto", use_kernel: bool = True,
                  interpret: bool | None = None,
                  plan_cache_size: int | None = None,
                  order: str = "soar", soar_chunk: int = 512,
@@ -322,10 +326,8 @@ class SceneEngine(ServingBase):
     @property
     def n_compilations(self) -> int:
         """Distinct jit signatures compiled so far (bucketed serving pays
-        one per bucket actually used); -1 if the running jax version
-        doesn't expose the cache-size probe."""
-        cache_size = getattr(self._apply, "_cache_size", None)
-        return int(cache_size()) if cache_size is not None else -1
+        one per bucket actually used)."""
+        return int(self._apply._cache_size())
 
     # -- streaming -----------------------------------------------------------
 

@@ -4,7 +4,7 @@ The property tests prefer real hypothesis (CI installs it); on images
 without it this shim keeps them running instead of dying at collection.
 It draws ``max_examples`` pseudo-random samples per test from a seed
 derived from the test name, biased toward the strategy boundaries (where
-off-by-ones live). Supported surface: ``given``, ``settings`` with
+off-by-ones live). Supported surface: ``given``, ``example``, ``settings`` with
 ``max_examples``/``deadline``, and ``strategies.integers/floats/lists``.
 """
 from __future__ import annotations
@@ -71,9 +71,20 @@ def settings(**kwargs):
     return deco
 
 
+def example(**drawn):
+    """Pin one explicit case; ``given`` runs pinned cases first."""
+    def deco(fn):
+        fn._examples = [drawn] + getattr(fn, "_examples", [])
+        return fn
+
+    return deco
+
+
 def given(*strats):
     def deco(fn):
         def wrapper(*args, **kwargs):
+            for pinned in getattr(wrapper, "_examples", []):
+                fn(*args, **pinned, **kwargs)
             n = getattr(wrapper, "_max_examples", _DEFAULT_MAX_EXAMPLES)
             rng = np.random.default_rng(zlib.crc32(fn.__name__.encode()))
             for i in range(n):
@@ -92,6 +103,7 @@ def given(*strats):
         wrapper.__signature__ = inspect.Signature()
         wrapper._max_examples = getattr(fn, "_max_examples",
                                         _DEFAULT_MAX_EXAMPLES)
+        wrapper._examples = getattr(fn, "_examples", [])
         return wrapper
 
     return deco

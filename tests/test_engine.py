@@ -126,7 +126,7 @@ def test_scene_engine_serves_batches_with_one_compilation():
     spec = engine.build_plan_spec([_scene(100), _scene(101)], cfg,
                                   mem_budget=BUDGET)
     assert any(d.backend == engine.SSPNNA for d in spec.levels)
-    eng = SceneEngine(cfg, params, batch=4, spec=spec, use_kernel=False)
+    eng = SceneEngine(cfg, params, batch=4, spec=spec)
     scenes = [_scene(200 + i) for i in range(6)]
     handles = eng.submit([SceneRequest(i, s)
                           for i, s in enumerate(scenes[:4])])
@@ -143,8 +143,7 @@ def test_scene_engine_serves_batches_with_one_compilation():
     # batched result == single-scene engine apply off the cached plan
     r0 = handles[0].result()
     plan0 = eng.cache.get_or_build(r0.scene, cfg, spec=spec)
-    single = engine.apply_unet(params, r0.scene.feats, plan0,
-                               use_kernel=False)
+    single = engine.apply_unet(params, r0.scene.feats, plan0)
     np.testing.assert_allclose(r0.logits, np.asarray(single),
                                rtol=1e-5, atol=1e-5)
     # resubmitting a known scene hits the plan cache and the jit cache

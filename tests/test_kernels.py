@@ -119,26 +119,22 @@ def test_resolve_interpret_gates_on_backend(monkeypatch):
     # explicit override always wins
     assert runtime.resolve_interpret(True) is True
     assert runtime.resolve_interpret(False) is False
-    # env override beats backend detection
-    monkeypatch.setenv(runtime.ENV_INTERPRET, "0")
-    assert runtime.resolve_interpret(None) is False
-    monkeypatch.setenv(runtime.ENV_INTERPRET, "1")
-    assert runtime.resolve_interpret(None) is True
-    assert runtime.resolve_interpret(False) is False  # arg still wins
     # default: compiled on TPU, interpreted everywhere else
-    monkeypatch.delenv(runtime.ENV_INTERPRET)
     expected = jax.default_backend() != "tpu"
     assert runtime.resolve_interpret(None) is expected
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert runtime.resolve_interpret(None) is False
+    # no environment variable can put a TPU into interpret mode
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    assert runtime.resolve_interpret(None) is False
+    assert runtime.resolve_interpret(True) is True  # only the caller can
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     assert runtime.resolve_interpret(None) is True
 
 
 def test_interpret_resolves_per_call_not_at_first_trace(monkeypatch):
-    """The env override must apply to later calls too: resolution happens in
+    """The backend gate applies to later calls too: resolution happens in
     the unjitted wrapper, keying the jit cache on the concrete mode."""
-    from repro.kernels import runtime
     from repro.kernels.sspnna import sspnna as mod
 
     seen = {}
@@ -149,7 +145,7 @@ def test_interpret_resolves_per_call_not_at_first_trace(monkeypatch):
     monkeypatch.setattr(mod, "_sspnna_tiles", fake)
     mod.sspnna_tiles(None, None, None)
     assert seen["interpret"] is (jax.default_backend() != "tpu")
-    monkeypatch.setenv(runtime.ENV_INTERPRET, "0")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mod.sspnna_tiles(None, None, None)
     assert seen["interpret"] is False
     mod.sspnna_tiles(None, None, None, interpret=True)  # explicit still wins
