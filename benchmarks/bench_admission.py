@@ -141,7 +141,7 @@ def run(quick: bool = False):
                  for i in range(batch)])
     warm.serve()
     st = warm.scheduler.stats[-1]
-    wave_ms = st.plan_ms + st.device_ms
+    wave_ms = st.plan_ms + st.inflight_ms
     warm.close()
     wb = bucketed_engine()
     wb.submit([SceneRequest(i, _scene_with(9000 + i, s))

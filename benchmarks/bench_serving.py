@@ -84,13 +84,13 @@ def _emit_pair(name, n_reqs, sync_wall, async_wall, async_stats):
     plan = sum(s.plan_ms for s in async_stats)
     span = sum(s.plan_span_ms for s in async_stats)
     wait = sum(s.plan_wait_ms for s in async_stats)
-    dev = sum(s.device_ms for s in async_stats)
+    dev = sum(s.inflight_ms for s in async_stats)
     overlap = overlap_fraction(span, wait)
     emit(f"serving/{name}_sync", sync_wall / n_reqs * 1e6,
          f"wall={sync_wall:.3f}s n={n_reqs}")
     emit(f"serving/{name}_async", async_wall / n_reqs * 1e6,
          f"wall={async_wall:.3f}s n={n_reqs} overlap_frac={overlap:.2f} "
-         f"plan_ms={plan:.0f} device_ms={dev:.0f} "
+         f"plan_ms={plan:.0f} inflight_ms={dev:.0f} "
          f"speedup={sync_wall / max(async_wall, 1e-9):.2f}x")
 
 

@@ -107,7 +107,7 @@ def main():
           f"plan cache {eng.cache.hits} hits / {eng.cache.misses} misses)")
     print(f"pipeline: plan={tm['plan_ms']:.0f}ms "
           f"(waited {tm['plan_wait_ms']:.0f}ms) "
-          f"device={tm['device_ms']:.0f}ms drain={tm['drain_ms']:.0f}ms "
+          f"inflight={tm['inflight_ms']:.0f}ms drain={tm['drain_ms']:.0f}ms "
           f"overlap_frac={tm['overlap_frac']:.2f}")
     if args.shards:
         halo = sum(st.notes.get("halo_rows", 0) for st in eng.wave_stats)

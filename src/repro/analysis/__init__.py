@@ -17,6 +17,10 @@ Four passes, one CLI (``python -m repro.analysis``), all returning
   ``launch.hlo_analysis``: forbidden-op sets, recompile budgets, modeled
   VMEM footprints.
 
+Beside the passes, ``spans`` holds the runtime's one timing helper: spans
+on the profiler's clock that the serving and plan layers' counters are
+read from.
+
 Submodules are imported lazily: lock-owning modules under ``src/repro``
 import ``repro.analysis.runtime`` at module load, and the passes import
 those same modules — eager imports here would cycle.
@@ -26,7 +30,7 @@ from __future__ import annotations
 from repro.analysis.findings import Finding, render
 
 _SUBMODULES = ("concurrency", "findings", "hlo_gates", "lint",
-               "plan_check", "runtime")
+               "plan_check", "runtime", "spans")
 
 __all__ = ["Finding", "render", *_SUBMODULES]
 

@@ -43,6 +43,7 @@ from repro.core.host_meta import (
 )
 from repro.core.soar import raster_order, soar_order
 from repro.analysis.runtime import ordered_condition, ordered_lock
+from repro.analysis.spans import span
 from repro.core.tiles import build_tile_plan, dma_tile_tables, max_tiles
 from repro.sparse.tensor import SparseVoxelTensor
 
@@ -365,7 +366,8 @@ class PlanCache:
         tables that would silently misroute rows on a different mesh."""
         tag = (f"v{_PLAN_VERSION}|top={topology}|{cfg!r}|"
                f"{sorted(build_kw.items())!r}")
-        return scene_key(t, tag)
+        with span("plan.key"):
+            return scene_key(t, tag)
 
     def get_or_build(self, t: SparseVoxelTensor, cfg, *, device: bool = True,
                      key: str | None = None, topology: str | None = None,
@@ -462,12 +464,13 @@ def level_geometry(t: SparseVoxelTensor, cfg) -> list[tuple]:
     type to avoid depending on the model zoo). Runs entirely on the host —
     part of the plan pass an async pipeline keeps off the device."""
     out = []
-    coords, mask, res = np.asarray(t.coords), np.asarray(t.mask), cfg.resolution
-    for li in range(len(cfg.widths)):
-        out.append((coords, mask, res))
-        if li < len(cfg.widths) - 1:
-            coords, mask = downsample_coords_np(coords, mask, res, 2)
-            res //= 2
+    with span("plan.geometry"):
+        coords, mask, res = np.asarray(t.coords), np.asarray(t.mask), cfg.resolution
+        for li in range(len(cfg.widths)):
+            out.append((coords, mask, res))
+            if li < len(cfg.widths) - 1:
+                coords, mask = downsample_coords_np(coords, mask, res, 2)
+                res //= 2
     return out
 
 
@@ -475,12 +478,13 @@ def _order_rows(sub_coir: COIR, coords, mask, how: str, chunk: int) -> np.ndarra
     """Ordering of active rows for tiling: SOAR (paper), raster, or active
     (occupancy order, cheapest)."""
     mask_np = np.asarray(mask)
-    if how == "soar":
-        # the submanifold CIRF *is* the adjacency map (self at the center)
-        return soar_order(np.asarray(sub_coir.indices), mask_np, chunk).order
-    if how == "raster":
-        return raster_order(np.asarray(coords), mask_np)
-    return np.flatnonzero(mask_np)
+    with span("plan.order"):
+        if how == "soar":
+            # the submanifold CIRF *is* the adjacency map (self at the center)
+            return soar_order(np.asarray(sub_coir.indices), mask_np, chunk).order
+        if how == "raster":
+            return raster_order(np.asarray(coords), mask_np)
+        return np.flatnonzero(mask_np)
 
 
 def dispatch_from_dataflow(
@@ -598,16 +602,17 @@ def _tile_arrays(cirf_indices, ordering, dispatch: Dispatch,
     """Build fixed-shape tile metadata (DMA-table layout) for one conv;
     None on budget overflow or when the plan needs shared-output-row tiles
     the fused kernel can't serve (callers fall back to reference)."""
-    try:
-        tp = build_tile_plan(
-            np.asarray(cirf_indices), ordering, dispatch.delta_o,
-            dispatch.delta_i,
-            n_tiles=dispatch.n_tiles if dispatch.n_tiles else None)
-    except ValueError:
-        return None
-    if tp.n_row_splits:  # fused output DMA overwrites; can't share rows
-        return None
-    dma = dma_tile_tables(tp, n_out)
+    with span("plan.tiles"):
+        try:
+            tp = build_tile_plan(
+                np.asarray(cirf_indices), ordering, dispatch.delta_o,
+                dispatch.delta_i,
+                n_tiles=dispatch.n_tiles if dispatch.n_tiles else None)
+        except ValueError:
+            return None
+        if tp.n_row_splits:  # fused output DMA overwrites; can't share rows
+            return None
+        dma = dma_tile_tables(tp, n_out)
     return TileArrays(dma.out_rows, dma.in_rows,
                       np.asarray(tp.local_idx), dma.pair_counts)
 
@@ -734,22 +739,24 @@ def _build_scene_plan(
     levels: list[LevelPlan] = []
     stats: list[dict] = []
     for li, (coords, mask, res) in enumerate(geometry):
-        sub_coir = build_cirf_np(coords, mask, coords, mask, offs3, res)
-        down = up = None
-        if li < len(cfg.widths) - 1:
-            dn_coords, dn_mask, _ = geometry[li + 1]
-            down_coir = build_cirf_np(
-                dn_coords, dn_mask, coords, mask, offs2, res, stride=2)
-            up_coir = transposed_coir_np(dn_coords, dn_mask, coords, mask,
-                                         res, 2, 2)
-            # resolution-changing convs stay on the coarse single dispatch
-            down = ConvPlan(down_coir)
-            up = ConvPlan(up_coir)
+        with span("plan.level", level=li):
+            down = up = None
+            with span("plan.cirf"):
+                sub_coir = build_cirf_np(coords, mask, coords, mask, offs3, res)
+                if li < len(cfg.widths) - 1:
+                    dn_coords, dn_mask, _ = geometry[li + 1]
+                    down_coir = build_cirf_np(
+                        dn_coords, dn_mask, coords, mask, offs2, res, stride=2)
+                    up_coir = transposed_coir_np(dn_coords, dn_mask, coords,
+                                                 mask, res, 2, 2)
+                    # resolution-changing convs stay on the coarse single dispatch
+                    down = ConvPlan(down_coir)
+                    up = ConvPlan(up_coir)
 
-        sub, info = _assemble_level(
-            sub_coir, coords, mask, li, cfg, spec=spec, plan_tiles=plan_tiles,
-            mem_budget=mem_budget, order=order, soar_chunk=soar_chunk,
-            autotune=autotune, breakers=breakers)
+            sub, info = _assemble_level(
+                sub_coir, coords, mask, li, cfg, spec=spec,
+                plan_tiles=plan_tiles, mem_budget=mem_budget, order=order,
+                soar_chunk=soar_chunk, autotune=autotune, breakers=breakers)
         stats.append(info)
         levels.append(LevelPlan(coords, mask, sub, down, up))
     return ScenePlan(tuple(levels), stats)
@@ -786,12 +793,13 @@ def _assemble_level(
             dispatch = spec.levels[li]
         else:
             ordering = _order_rows(sub_coir, coords, mask, order, soar_chunk)
-            attrs = spade.extract_attributes(
-                np.asarray(sub_coir.indices), np.asarray(mask), ordering)
-            layer = _layer_spec(f"level{li}", n_active, cfg.widths[li])
-            df = spade.explore(layer, {"CIRF": attrs, "CORF": attrs},
-                               mem_budget)
-            dispatch = dispatch_from_dataflow(df, attrs, n_active)
+            with span("plan.spade"):
+                attrs = spade.extract_attributes(
+                    np.asarray(sub_coir.indices), np.asarray(mask), ordering)
+                layer = _layer_spec(f"level{li}", n_active, cfg.widths[li])
+                df = spade.explore(layer, {"CIRF": attrs, "CORF": attrs},
+                                   mem_budget)
+                dispatch = dispatch_from_dataflow(df, attrs, n_active)
             info["arf"] = float(attrs.arf_avg[0])
             info["da_elems"] = df.da_elems
             if autotune is not None:
@@ -825,12 +833,18 @@ def _assemble_level(
             if tiles is None:  # tile budget overflow: coarse dispatch
                 info["tile_overflow"] = True
                 dispatch = REFERENCE_DISPATCH
-            elif not dispatch.n_tiles:
-                # adaptive mode: record the realized tile count
-                dispatch = Dispatch(
-                    dispatch.backend, dispatch.flavor, dispatch.walk,
-                    dispatch.delta_o, dispatch.delta_i,
-                    int(tiles.out_rows.shape[0]), dispatch.block_n)
+            else:
+                # the grid's tiles, and those holding a pair (the kernel
+                # skips the others as dead)
+                info["n_tiles"] = int(tiles.pair_counts.shape[0])
+                info["n_live_tiles"] = int(np.count_nonzero(
+                    tiles.pair_counts))
+                if not dispatch.n_tiles:
+                    # adaptive mode: record the realized tile count
+                    dispatch = Dispatch(
+                        dispatch.backend, dispatch.flavor, dispatch.walk,
+                        dispatch.delta_o, dispatch.delta_i,
+                        int(tiles.out_rows.shape[0]), dispatch.block_n)
     info["dispatch"] = dispatch
     return ConvPlan(sub_coir, tiles, dispatch), info
 
@@ -914,17 +928,20 @@ class StreamPlanState:
                     break
                 self._cond.wait(remaining)
             try:
-                t0 = time.perf_counter()
-                if self._next_frame != frame_no or self._gap:
-                    # gap in the stream (shed/failed predecessor, or an
-                    # out-of-order replay): the cached delta base is stale
-                    self.meta.n = None
-                self._gap = False
-                meta = self.meta.step(np.asarray(t.coords),
-                                      np.asarray(t.mask), ego_shift,
-                                      min_overlap=self.min_overlap)
-                plan = self._assemble(meta)
-                plan_ms = (time.perf_counter() - t0) * 1e3
+                with span("plan.frame", frame=frame_no) as sp:
+                    if self._next_frame != frame_no or self._gap:
+                        # gap in the stream (shed/failed predecessor, or an
+                        # out-of-order replay): the cached delta base is stale
+                        self.meta.n = None
+                    self._gap = False
+                    meta = self.meta.step(np.asarray(t.coords),
+                                          np.asarray(t.mask), ego_shift,
+                                          min_overlap=self.min_overlap)
+                    with span("plan.rebuild" if meta.mode == "rebuilt"
+                              else "plan.patch", mode=meta.mode,
+                              overlap=meta.overlap):
+                        plan = self._assemble(meta)
+                plan_ms = sp.wall_ms
                 self._prev_plan = plan
                 self.counts[meta.mode] += 1
                 self._overlap_sum += meta.overlap

@@ -354,6 +354,8 @@ def _sspnna_fused(
         # the manual double buffer carries state from step i to i+1
         compiler_params=_SEQUENTIAL,
         interpret=interpret,
+        # the kernel's name in HLO and in profiler traces
+        name="sspnna_fused",
     )(counts, in_dma, in_dma, out_dma, local_idx, groups, zeros, weights)
     out = out.transpose(1, 0, 2).reshape(n_out + 1, n_p)
     return out[:n_out, :n]

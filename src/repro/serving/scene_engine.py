@@ -58,7 +58,7 @@ Stage split (the paper's offline-pass/execution overlap, served):
 
 ``sync=True`` (default) runs the same stages back-to-back — bitwise
 identical results given the same admitted wave order, no overlap;
-``sync=False`` pipelines them and reports ``plan_ms`` / ``device_ms`` /
+``sync=False`` pipelines them and reports ``plan_ms`` / ``inflight_ms`` /
 ``overlap_frac`` per wave via ``wave_stats`` / ``timings()``.
 
 Short waves are padded with a copy of the first scene's plan and zero
@@ -79,6 +79,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.runtime import ordered_lock
+from repro.analysis.spans import span
 from repro.core.host_meta import pack_stream_frame_np
 from repro.engine import api as engine_api
 from repro.engine.context import ExecutionContext
@@ -516,9 +517,13 @@ class SceneEngine(ServingBase):
         # Stream frames upload through their StreamPlanState's per-leaf
         # identity memo instead, so a patched frame re-uploads only the
         # tables the delta actually touched.
-        plans = [p[4].device_plan(p[2]) if p[0] == "stream"
-                 else self.cache.adopt(p[0], p[1], device=True)
-                 for p in payloads]
+        with span("serve.upload") as up:
+            plans = [p[4].device_plan(p[2]) if p[0] == "stream"
+                     else self.cache.adopt(p[0], p[1], device=True)
+                     for p in payloads]
+            if self.layout is None:
+                feats = self._wave_feats(reqs, payloads)
+        stats.notes["upload_ms"] = up.wall_ms
         if self.layout is not None:
             # the pinned halo budget promises one jit signature across
             # every wave; a diverging plan (wrong capacity, re-pinned
@@ -558,11 +563,15 @@ class SceneEngine(ServingBase):
                 raise RuntimeError(
                     f"wave mixes capacity buckets {sorted(caps)}; bucketed "
                     "serving admits one bucket per wave")
-            feats = [jnp.asarray(p[2]) for p in payloads]
-        else:
-            feats = [jnp.asarray(p[3]) if p[0] == "stream"
-                     else r.scene.feats
-                     for r, p in zip(reqs, payloads)]
+        # tiles of the fused kernel's levels in the wave's own plans (not
+        # the padding copies): live tiles hold at least one pair
+        levels = [i for p in payloads
+                  for i in (p[2] if p[0] == "stream" else p[1]).stats or ()
+                  if "n_tiles" in i]
+        if levels:
+            stats.notes["sspnna_tiles"] = sum(i["n_tiles"] for i in levels)
+            stats.notes["sspnna_live_tiles"] = sum(
+                i["n_live_tiles"] for i in levels)
         s_infos = [r.plan_info for r in reqs
                    if isinstance(r, StreamFrameRequest)]
         if s_infos:
@@ -577,6 +586,14 @@ class SceneEngine(ServingBase):
             plans.append(plans[0])
             feats.append(jnp.zeros_like(feats[0]))
         return self._apply(self.params, feats, plans)
+
+    def _wave_feats(self, reqs: list[SceneRequest], payloads) -> list:
+        """The wave's features on the device: a bucket's re-packed rows, a
+        stream frame's canonical rows, or the scene's own."""
+        if self.family is not None:
+            return [jnp.asarray(p[2]) for p in payloads]
+        return [jnp.asarray(p[3] if p[0] == "stream" else r.scene.feats)
+                for r, p in zip(reqs, payloads)]
 
     def _drain_stage(self, reqs: list[SceneRequest], logits) -> None:
         if isinstance(logits, list):  # sharded mode: per-scene handles

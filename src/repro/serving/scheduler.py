@@ -79,6 +79,14 @@ hidden behind device execution (0 in sync mode by construction);
 ``queue_depth`` / ``bucket`` / ``fill_frac`` / ``n_shed`` describe what
 admission saw and decided. ``slo_stats()`` aggregates the per-request
 view: p50/p99 latency, deadline goodput, shed counts.
+
+Every stage runs in a span (``repro.analysis.spans``) and its ``WaveStats``
+time is read from that span: ``serve.admit``, ``serve.plan_wait``,
+``serve.dispatch`` and ``serve.drain`` on the serving thread, and one
+``plan.request`` per request on the planner threads, whose nested plan
+phases sum into ``plan_phase_ms``. Under a profiler session the spans land
+on the trace beside the device ops, so an idle gap on the chip shows the
+stage the host was in.
 """
 from __future__ import annotations
 
@@ -91,6 +99,7 @@ from concurrent.futures import TimeoutError as _FutureTimeout
 from dataclasses import dataclass, field
 
 from repro.analysis.runtime import ordered_lock
+from repro.analysis.spans import span
 from repro.serving.faults import WorkerDeath
 
 # request lifecycle states (mirrored by serving.api.ServeRequest.status)
@@ -158,15 +167,19 @@ class WaveStats:
     rids: tuple
     sync: bool
     plan_ms: float = 0.0       # host plan-stage work, summed over requests
+    plan_cpu_ms: float = 0.0   # planner threads' CPU time in that work
     plan_span_ms: float = 0.0  # wall-clock span of this wave's plan builds
     plan_wait_ms: float = 0.0  # span remainder the dispatcher waited on
     dispatch_ms: float = 0.0   # host time enqueueing the jitted call
-    device_ms: float = 0.0     # dispatch call -> results drained
+    inflight_ms: float = 0.0   # dispatch call -> results drained (host clock)
     drain_ms: float = 0.0      # time blocked in readback
     queue_depth: int = 0       # queue length when admission ran
     n_shed: int = 0            # requests shed by this admission pass
     bucket: object = None      # bucket_of key the wave was filled from
     fill_frac: float = 1.0     # admitted / batch (padding slots are waste)
+    #: plan work by the name of the span it ran in (``plan.order``, ...),
+    #: summed over requests; nested phases count in each enclosing one
+    plan_phase_ms: dict = field(default_factory=dict)
     #: engine-specific observations the dispatch stage records (e.g. the
     #: sharded scene engine's per-shard plan builds / halo rows)
     notes: dict = field(default_factory=dict)
@@ -175,6 +188,13 @@ class WaveStats:
     def overlap_frac(self) -> float:
         """Fraction of plan wall-clock hidden behind device execution."""
         return overlap_fraction(self.plan_span_ms, self.plan_wait_ms)
+
+    def add_plan(self, sp) -> None:
+        """Count one request's ``plan.request`` span."""
+        self.plan_ms += sp.wall_ms
+        self.plan_cpu_ms += sp.cpu_ms
+        for name, ms in sp.phase_ms.items():
+            self.plan_phase_ms[name] = self.plan_phase_ms.get(name, 0.0) + ms
 
 
 def _now_ms() -> float:
@@ -416,6 +436,15 @@ class WaveScheduler:
                 or getattr(r, "_stream_frame", 0) == heads[r._stream_key]]
 
     def _admit(self) -> list:
+        """Form the next wave (:meth:`_form_wave`) in a ``serve.admit``
+        span: how deep the queue was, and how many requests it took."""
+        with span("serve.admit", wave=self._wave,
+                  queue_depth=len(self.queue)) as sp:
+            reqs = self._form_wave()
+            sp.note(n=len(reqs))
+        return reqs
+
+    def _form_wave(self) -> list:
         """Form the next wave. FIFO without a policy/bucket hook; with one,
         greedy continuous batching: shed expired requests, then fill from
         the most urgent compatible (same-bucket) candidates, preempting
@@ -601,16 +630,17 @@ class WaveScheduler:
 
     def timings(self) -> dict:
         """Aggregate pipeline timings over every wave served so far."""
-        span = sum(s.plan_span_ms for s in self.stats)
+        plan_span = sum(s.plan_span_ms for s in self.stats)
         wait = sum(s.plan_wait_ms for s in self.stats)
         return {
             "waves": len(self.stats),
             "plan_ms": sum(s.plan_ms for s in self.stats),
-            "plan_span_ms": span,
+            "plan_cpu_ms": sum(s.plan_cpu_ms for s in self.stats),
+            "plan_span_ms": plan_span,
             "plan_wait_ms": wait,
-            "device_ms": sum(s.device_ms for s in self.stats),
+            "inflight_ms": sum(s.inflight_ms for s in self.stats),
             "drain_ms": sum(s.drain_ms for s in self.stats),
-            "overlap_frac": overlap_fraction(span, wait),
+            "overlap_frac": overlap_fraction(plan_span, wait),
         }
 
     def slo_stats(self) -> dict:
@@ -682,15 +712,25 @@ class WaveScheduler:
             self.on_idle(self)
         return self.completed
 
-    def _timed_plan(self, req):
-        t0 = _now_ms()
-        inj = self.faults
-        if inj is not None:
-            rid = getattr(req, "rid", None)
-            inj.maybe_fail("worker_death", rid=rid)
-            inj.maybe_fail("plan", rid=rid)
-        payload = self._plan(req)
-        return payload, t0, _now_ms()
+    def _timed_plan(self, req, wave: int):
+        """-> (payload, the request's ``plan.request`` span)."""
+        rid = getattr(req, "rid", None)
+        with span("plan.request", rid=rid, wave=wave) as sp:
+            inj = self.faults
+            if inj is not None:
+                inj.maybe_fail("worker_death", rid=rid)
+                inj.maybe_fail("plan", rid=rid)
+            payload = self._plan(req)
+        return payload, sp
+
+    def _timed_dispatch(self, reqs, payloads, st, budget):
+        """-> (device handle, the wave's ``serve.dispatch`` span)."""
+        with span("serve.dispatch", wave=st.wave) as sp:
+            handle = self._with_timeout(
+                self._dispatch_with_faults, (reqs, payloads, st), budget,
+                "dispatch")
+        st.dispatch_ms = sp.wall_ms
+        return handle, sp
 
     def _dispatch_with_faults(self, reqs, payloads, st):
         inj = self.faults
@@ -716,31 +756,25 @@ class WaveScheduler:
             stage = "plan"
             try:
                 payloads = []
-                for r in reqs:
-                    payload, t0, t1 = self._with_timeout(
-                        self._timed_plan, (r,), budget, "plan")
-                    payloads.append(payload)
-                    st.plan_ms += t1 - t0
-                st.plan_span_ms = st.plan_ms   # serial builds
-                st.plan_wait_ms = st.plan_span_ms  # nothing hidden in sync
+                with span("serve.plan_wait", wave=st.wave) as wait:
+                    for r in reqs:
+                        payload, sp = self._with_timeout(
+                            self._timed_plan, (r, st.wave), budget, "plan")
+                        payloads.append(payload)
+                        st.add_plan(sp)
+                # serial builds, nothing hidden behind the device in sync
+                st.plan_span_ms = st.plan_wait_ms = wait.wall_ms
                 stage = "dispatch"
-                t_disp = _now_ms()
-                handle = self._with_timeout(
-                    self._dispatch_with_faults, (reqs, payloads, st),
-                    budget, "dispatch")
-                st.dispatch_ms = _now_ms() - t_disp
+                handle, disp = self._timed_dispatch(reqs, payloads, st,
+                                                    budget)
                 stage = "drain"
-                t_drain = _now_ms()
-                self._drain(reqs, handle)
-                st.drain_ms = _now_ms() - t_drain
-                st.device_ms = _now_ms() - t_disp
+                self._drain_one((reqs, st, handle, disp.start_ms))
             except BaseException as e:
                 if self._contained and self._containable(e):
                     self._handle_wave_failure(reqs, e, stage)
                     continue
                 self._requeue([reqs])
                 raise
-            self._finish(reqs, st)
 
     def _pool_or_start(self) -> ThreadPoolExecutor:
         # lazy and persistent: paced workloads call run() per arrival group
@@ -802,7 +836,7 @@ class WaveScheduler:
                     waves_left -= 1
                     failed = reqs  # cover the gap until safely planned
                     st = self._new_stats(reqs, sync=False)
-                    wave_futs = [pool.submit(self._timed_plan, r)
+                    wave_futs = [pool.submit(self._timed_plan, r, st.wave)
                                  for r in reqs]
                     planned.append((reqs, st, wave_futs))
                     failed = []
@@ -815,29 +849,27 @@ class WaveScheduler:
                     failed = reqs
                     stage = "plan"
                     try:
-                        t_gather = _now_ms()
-                        payloads, starts, ends = [], [], []
-                        for f in futs:
-                            try:
-                                payload, t0, t1 = f.result(timeout=budget)
-                            except (_FutureTimeout, TimeoutError) as te:
-                                raise StageTimeout(
-                                    f"plan stage exceeded {budget:.3f}s "
-                                    f"watchdog") from te
-                            payloads.append(payload)
-                            st.plan_ms += t1 - t0
-                            starts.append(t0)
-                            ends.append(t1)
-                        if ends:
-                            st.plan_span_ms = max(ends) - min(starts)
-                        st.plan_wait_ms = _now_ms() - t_gather
+                        payloads, spans = [], []
+                        with span("serve.plan_wait", wave=st.wave) as wait:
+                            for f in futs:
+                                try:
+                                    payload, sp = f.result(timeout=budget)
+                                except (_FutureTimeout, TimeoutError) as te:
+                                    raise StageTimeout(
+                                        f"plan stage exceeded {budget:.3f}s "
+                                        f"watchdog") from te
+                                payloads.append(payload)
+                                spans.append(sp)
+                                st.add_plan(sp)
+                        if spans:
+                            st.plan_span_ms = (
+                                max(sp.end_ms for sp in spans)
+                                - min(sp.start_ms for sp in spans))
+                        st.plan_wait_ms = wait.wall_ms
                         stage = "dispatch"
-                        t_disp = _now_ms()
-                        handle = self._with_timeout(
-                            self._dispatch_with_faults, (reqs, payloads, st),
-                            budget, "dispatch")
-                        st.dispatch_ms = _now_ms() - t_disp
-                        inflight.append((reqs, st, handle, t_disp))
+                        handle, disp = self._timed_dispatch(
+                            reqs, payloads, st, budget)
+                        inflight.append((reqs, st, handle, disp.start_ms))
                     except BaseException as e:
                         if not (contained and self._containable(e)):
                             raise
@@ -887,9 +919,8 @@ class WaveScheduler:
 
     def _drain_one(self, item) -> None:
         reqs, st, handle, t_disp = item
-        t0 = _now_ms()
-        self._drain(reqs, handle)
-        t1 = _now_ms()
-        st.drain_ms = t1 - t0
-        st.device_ms = t1 - t_disp
+        with span("serve.drain", wave=st.wave) as sp:
+            self._drain(reqs, handle)
+        st.drain_ms = sp.wall_ms
+        st.inflight_ms = sp.end_ms - t_disp
         self._finish(reqs, st)

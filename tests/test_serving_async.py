@@ -75,12 +75,12 @@ def test_async_wave_stats_and_timings(setup):
     _serve(eng, [_scene(400 + i) for i in range(4)])
     assert len(eng.wave_stats) == 2
     for st in eng.wave_stats:
-        assert st.plan_ms > 0 and st.device_ms > 0
+        assert st.plan_ms > 0 and st.inflight_ms > 0
         assert 0.0 <= st.overlap_frac <= 1.0
         assert not st.sync
     tm = eng.timings()
     assert tm["waves"] == 2
-    assert set(tm) >= {"plan_ms", "plan_wait_ms", "device_ms", "drain_ms",
+    assert set(tm) >= {"plan_ms", "plan_wait_ms", "inflight_ms", "drain_ms",
                        "overlap_frac"}
     # sync mode reports zero overlap by construction
     es = SceneEngine(cfg, params, batch=2, sync=True)
