@@ -2,7 +2,9 @@
 
 Host-side (numpy) offline pass, exactly the paper's algorithm:
 
-1. Build the adjacency map (from ``repro.core.hashgrid`` neighbour tables).
+1. Build the adjacency map (from ``repro.core.hashgrid`` neighbour tables)
+   as CSR neighbour lists of the active rows, so the pass costs time in
+   proportion to active voxels and edges, not to the table's capacity.
 2. Pick the unselected voxel with the minimum number of neighbours as the
    root (a surface corner).
 3. Grow an m-ary tree in breadth-first order: pop voxels from the Neighbour
@@ -33,55 +35,56 @@ class SoarResult:
         return len(self.chunk_starts) - 1
 
 
-def _neighbor_lists(neighbor_table: np.ndarray) -> list[np.ndarray]:
-    """Per-voxel neighbour index lists from a (V, K) table (-1 holes),
-    excluding self-edges."""
-    v = neighbor_table.shape[0]
-    lists = []
-    for i in range(v):
-        nb = neighbor_table[i]
-        nb = nb[(nb >= 0) & (nb != i)]
-        lists.append(nb)
-    return lists
-
-
 def soar_order(
     neighbor_table: np.ndarray,
     active_mask: np.ndarray,
     max_chunk_voxels: int,
 ) -> SoarResult:
-    """Chunked breadth-first reordering of the active voxels."""
-    v = neighbor_table.shape[0]
-    nbrs = _neighbor_lists(neighbor_table)
-    degree = np.array([len(n) for n in nbrs])
-    active = np.asarray(active_mask, bool).copy()
-    selected = np.zeros(v, bool)
-    # min-degree order among active voxels, used for root selection
-    root_order = np.argsort(degree + np.where(active, 0, 1 << 30), kind="stable")
+    """Chunked breadth-first reordering of the active voxels.
+
+    The BFS runs over positions among the active rows (``act`` maps them
+    back to table rows). A voxel's degree counts its valid non-self
+    entries, inactive neighbours included; its CSR list drops those
+    neighbours, which the BFS would never enqueue.
+    """
+    table = np.asarray(neighbor_table)
+    act = np.flatnonzero(np.asarray(active_mask, bool))
+    n = len(act)
+    local = np.full(table.shape[0], -1, np.int64)  # table row -> position
+    local[act] = np.arange(n)
+    rows = table[act]
+    valid = (rows >= 0) & (rows != act[:, None])
+    degree = valid.sum(axis=1)
+    nb = local[np.where(valid, rows, act[:, None])]
+    keep = valid & (nb >= 0)
+    flat = nb[keep].tolist()
+    starts = np.concatenate([[0], np.cumsum(keep.sum(axis=1))]).tolist()
+    deg = degree.tolist()
+    # min-degree order of the active voxels, used for root selection
+    root_order = np.argsort(degree, kind="stable").tolist()
     root_ptr = 0
+    selected = bytearray(n)
 
     order: list[int] = []
     chunk_starts = [0]
     queue: deque[int] = deque()
-    n_active = int(active.sum())
     chunk_count = 0
 
     def next_root() -> int:
         nonlocal root_ptr
         # prefer min-degree voxel from the Neighbour Queue (paper), else the
         # globally min-degree unselected voxel
-        if queue:
-            cands = [q for q in queue if active[q] and not selected[q]]
-            if cands:
-                return min(cands, key=lambda q: degree[q])
-        while root_ptr < v:
+        cands = [q for q in queue if not selected[q]]
+        if cands:
+            return min(cands, key=deg.__getitem__)
+        while root_ptr < n:
             r = root_order[root_ptr]
             root_ptr += 1
-            if active[r] and not selected[r]:
-                return int(r)
+            if not selected[r]:
+                return r
         return -1
 
-    while len(order) < n_active:
+    while len(order) < n:
         root = next_root()
         if root < 0:
             break
@@ -89,14 +92,14 @@ def soar_order(
         queue.append(root)
         while queue and chunk_count < max_chunk_voxels:
             u = queue.popleft()
-            if selected[u] or not active[u]:
+            if selected[u]:
                 continue
-            selected[u] = True
+            selected[u] = 1
             order.append(u)
             chunk_count += 1
-            for w in nbrs[u]:
-                if active[w] and not selected[w]:
-                    queue.append(int(w))
+            for w in flat[starts[u]:starts[u + 1]]:
+                if not selected[w]:
+                    queue.append(w)
         if chunk_count >= max_chunk_voxels or not queue:
             if chunk_count:
                 chunk_starts.append(len(order))
@@ -105,7 +108,8 @@ def soar_order(
             # we keep it until next_root() has inspected it, then clear there
     if chunk_starts[-1] != len(order):
         chunk_starts.append(len(order))
-    return SoarResult(np.array(order, np.int64), np.array(chunk_starts, np.int64))
+    rows_in_order = act[np.array(order, np.int64)].astype(np.int64, copy=False)
+    return SoarResult(rows_in_order, np.array(chunk_starts, np.int64))
 
 
 def soar_hierarchical(

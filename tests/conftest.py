@@ -45,3 +45,22 @@ def make_shell_scene(rng, resolution=24, channels=4):
     dense = np.zeros((r, r, r, channels), np.float32)
     dense[occ] = rng.normal(size=(occ.sum(), channels)).astype(np.float32)
     return dense
+
+
+@pytest.fixture(scope="module")
+def shell():
+    """A 28^3 sphere shell: its tensor, 3x3x3 neighbour table and
+    submanifold CIRF indices."""
+    import jax.numpy as jnp
+
+    from repro.core.hashgrid import build_neighbor_table, kernel_offsets
+    from repro.core.sparse_conv import submanifold_coir
+    from repro.sparse.tensor import from_dense
+
+    rng = np.random.default_rng(7)
+    dense = make_shell_scene(rng, 28, 4)
+    t = from_dense(dense)
+    nbr = np.asarray(build_neighbor_table(
+        t.coords, t.mask, jnp.asarray(kernel_offsets(3)), 28))
+    coir = submanifold_coir(t, 28, 3)
+    return t, nbr, np.asarray(coir.indices)

@@ -1,24 +1,7 @@
 """SOAR / SPADE / CAROM / scheduler behaviour."""
-import jax.numpy as jnp
 import numpy as np
-import pytest
 
-from conftest import make_shell_scene
 from repro.core import carom, schedule, soar, spade
-from repro.core.hashgrid import build_neighbor_table, kernel_offsets
-from repro.core.sparse_conv import submanifold_coir
-from repro.sparse.tensor import from_dense
-
-
-@pytest.fixture(scope="module")
-def shell():
-    rng = np.random.default_rng(7)
-    dense = make_shell_scene(rng, 28, 4)
-    t = from_dense(dense)
-    nbr = np.asarray(build_neighbor_table(
-        t.coords, t.mask, jnp.asarray(kernel_offsets(3)), 28))
-    coir = submanifold_coir(t, 28, 3)
-    return t, nbr, np.asarray(coir.indices)
 
 
 def test_soar_is_permutation(shell):
