@@ -25,12 +25,12 @@ import check
 
 def control_readings(session: dict, dtypes: dict) -> dict:
     """A control's ``gap`` on the run's sampled requests."""
-    traffic, win, w, cfg = (session[k] for k in
-                            ("traffic", "window", "weights", "cfg"))
+    traffic, win, w, cfg, model = (session[k] for k in (
+        "traffic", "window", "weights", "cfg", "model"))
     worst = 0.0
     for _, coords, feats, _ in check.sample(traffic, win):
-        want = check.reference_logits(coords, feats, w, cfg)
-        ctl = check.reference_logits(coords, feats, w, cfg, **dtypes)
+        want = model.logits(w, coords, feats, cfg)
+        ctl = model.logits(w, coords, feats, cfg, **dtypes)
         worst = max(worst, check.gap(ctl, want))
     return {"gap": worst}
 

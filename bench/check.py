@@ -2,11 +2,11 @@
 
 Once the window has closed, a sample of the requests it finished (drawn
 from the seed, with the largest room or each stream's last frame in it) is
-run through the plain reference (``bench/reference.py``, float32 at
-``highest`` precision) from the same inputs and weights, and the served
-logits of every active voxel are compared with it. The number compared,
-``gap``, is the widest |served - reference| over the sample, as a share of
-the reference's largest |logit| in that request.
+run through the plain reference (the architecture's ``logits``,
+``bench/plug.py``: float32 at ``highest`` precision) from the same inputs
+and weights, and the served logits of every active voxel are compared with
+it. The number compared, ``gap``, is the widest |served - reference| over
+the sample, as a share of the reference's largest |logit| in that request.
 
 The configuration file's ``check.limits`` holds the limit; PERF.md gives
 the readings it was set from: the program's over a dozen seeds and more,
@@ -20,8 +20,6 @@ from __future__ import annotations
 
 import numpy as np
 
-import reference
-
 CONTROL = {"operand_dtype": "float8_e4m3fn"}
 BF16 = {"operand_dtype": "bfloat16", "store_dtype": "bfloat16"}
 
@@ -34,12 +32,6 @@ def gap(got: np.ndarray, want: np.ndarray) -> float:
     return float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-30))
 
 
-def reference_logits(coords, feats, w, cfg, **dtypes):
-    return reference.logits(
-        w, coords, feats, widths=cfg["n_planes"], reps=cfg["block_reps"],
-        resolution=cfg["full_scale"], capacity=cfg["capacity"], **dtypes)
-
-
 def sample(traffic, win):
     """(record, active coords, active feats, active-row mask) of each
     sampled request."""
@@ -49,8 +41,9 @@ def sample(traffic, win):
         yield recs[o], coords[mask], feats[mask], mask
 
 
-def check(traffic, win, w, cfg) -> dict:
-    """The verdict on one run: each compared number beside its limit."""
+def check(traffic, win, w, cfg, model) -> dict:
+    """The verdict on one run: each compared number beside its limit;
+    ``model`` is the architecture's module."""
     limit = cfg["check"]["limits"]["gap"]
     worst, n = 0.0, 0
     for rec, coords, feats, mask in sample(traffic, win):
@@ -58,7 +51,7 @@ def check(traffic, win, w, cfg) -> dict:
         if rec.logits is None:
             worst = float("inf")
             continue
-        want = reference_logits(coords, feats, w, cfg)
+        want = model.logits(w, coords, feats, cfg)
         worst = max(worst, gap(np.asarray(rec.logits)[mask], want))
     numbers = [{"name": "gap", "value": worst, "limit": limit,
                 "ok": worst <= limit}]

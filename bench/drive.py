@@ -37,9 +37,9 @@ PIN_SEED, WARM_SEED = 7_000_000_001, 7_000_000_002
 ROOMS, STREAMS, SAMPLE = 5, 6, 8
 
 
-def _room(seed_seq, target: int, n_objects: int, ucfg):
+def _room(seed_seq, target: int, n_objects: int, cfg: dict):
     return scenes.room_with_voxels(seed_seq, target, n_objects,
-                                   ucfg.resolution, ucfg.capacity)
+                                   cfg["full_scale"], cfg["capacity"])
 
 
 @dataclass
@@ -69,9 +69,9 @@ class Window:
         return self.t1 - self.t0
 
 
-def pin_rooms(mix: dict, ucfg) -> list:
+def pin_rooms(mix: dict, cfg: dict) -> list:
     p = mix["pin"]
-    rooms = [_room([PIN_SEED, i], p["voxels"], p["objects"], ucfg)
+    rooms = [_room([PIN_SEED, i], p["voxels"], p["objects"], cfg)
              for i in range(p["rooms"])]
     return [sut.scene(c, f, m) for c, f, _, m in rooms]
 
@@ -123,18 +123,18 @@ class _Client:
 
 
 class RoomsTraffic:
-    def __init__(self, mix: dict, seed: int, seconds: float, ucfg):
-        self.mix, self.seed, self.ucfg = mix, seed, ucfg
+    def __init__(self, mix: dict, seed: int, seconds: float, cfg: dict):
+        self.mix, self.seed = mix, seed
         n = max(mix["outstanding"], round(mix["rate_per_s"] * seconds))
         v = mix["voxels"]
         sizes, objs = scenes.room_sizes(n, seed, v["median"], v["sigma"],
                                         v["lo"], v["hi"], *mix["objects"])
         self.pool = [_room([seed, ROOMS, i], int(sizes[i]), int(objs[i]),
-                           ucfg) for i in range(n)]
+                           cfg) for i in range(n)]
         # small warm-up rooms: the wave program's shapes do not depend on
         # the room, and a small room plans sooner
         self.warm = [_room([WARM_SEED, i], int(mix["warm_voxels"]),
-                           mix["objects"][0], ucfg) for i in range(3)]
+                           mix["objects"][0], cfg) for i in range(3)]
         rng = np.random.default_rng([seed, SAMPLE])
         self.sample = set(rng.choice(n, size=min(mix["check"]["sample"], n),
                                      replace=False).tolist())
@@ -186,11 +186,11 @@ class RoomsTraffic:
 
 
 class StreamTraffic:
-    def __init__(self, mix: dict, seed: int, seconds: float, ucfg):
-        self.mix, self.seed, self.ucfg = mix, seed, ucfg
+    def __init__(self, mix: dict, seed: int, seconds: float, cfg: dict):
+        self.mix, self.seed = mix, seed
         self.sensors = [
             scenes.FixedSensorStream(
-                [seed, STREAMS, s], ucfg.resolution, ucfg.capacity,
+                [seed, STREAMS, s], cfg["full_scale"], cfg["capacity"],
                 room_voxels=mix["room_voxels"], n_objects=mix["objects"],
                 object_voxels=mix["object_voxels"], dropout=mix["dropout"])
             for s in range(mix["streams"])]
@@ -279,5 +279,7 @@ class StreamTraffic:
 KINDS = {"rooms": RoomsTraffic, "stream": StreamTraffic}
 
 
-def traffic(mix: dict, seed: int, seconds: float, ucfg):
-    return KINDS[mix["kind"]](mix, seed, seconds, ucfg)
+def traffic(mix: dict, seed: int, seconds: float, cfg: dict):
+    """The mix's traffic on the grid and capacity of the configuration
+    (``full_scale``, ``capacity``)."""
+    return KINDS[mix["kind"]](mix, seed, seconds, cfg)

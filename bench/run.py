@@ -5,11 +5,16 @@
 
 The cell (``workloads`` in ``BENCHMARK.json``) names a configuration
 (``bench/configs/<config>.json``) and a traffic mix
-(``bench/traffic/<mix>.json``); the per-layer metrics are read by
-``bench/metrics/<base>.py``, where ``<base>`` is the metric's name up to
-its first dot: ``idle_pct.rooms`` and a later ``idle_pct.stream`` share
-``idle_pct.py``. Adding a cell, config, mix or metric adds files and
-entries; no code here changes.
+(``bench/traffic/<mix>.json``). The configuration's ``arch`` names its
+architecture's two modules (``bench/archs/<arch>.py`` and
+``<arch>_sut.py``, ``bench/plug.py``): its weights, reference and work
+counts, and the program that serves it; the harness reads only the
+configuration's neutral keys (``full_scale``, ``capacity``,
+``input_features``, ``nClasses``, ``batch``, ``check.limits``). The
+per-layer metrics are read by ``bench/metrics/<base>.py``, where ``<base>``
+is the metric's name up to its first dot: ``idle_pct.rooms`` and a later
+``idle_pct.stream`` share ``idle_pct.py``. Adding a cell, config, mix,
+metric or architecture adds files and entries; no code here changes.
 
 A run: fail unless JAX finds a TPU with the cell's chips; turn on the
 persistent compile cache; make the weights and the traffic from the seed;
@@ -30,7 +35,6 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -41,6 +45,8 @@ BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 sys.path.insert(0, str(BENCH))
 sys.path.insert(0, str(ROOT / "src"))
+
+import plug  # noqa: E402
 
 
 class BenchError(RuntimeError):
@@ -73,11 +79,7 @@ def reader(metric: str, root: Path = ROOT):
     """``read`` of ``bench/metrics/<base>.py``, the metric's name up to its
     first dot (the rest names the cells' family)."""
     base = metric.split(".", 1)[0]
-    path = root / "bench" / "metrics" / f"{base}.py"
-    spec = importlib.util.spec_from_file_location("metric_" + base, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return plug.load(root / "bench" / "metrics" / f"{base}.py").read
 
 
 def device_check(chips: int):
@@ -135,41 +137,41 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     the built engine before the warm-up (tests plant faults through it).
     ``session`` lets several runs share one process (``calibrate.py``): it
     keeps the pinned spec, which no seed changes, and receives the run's
-    traffic, window and weights."""
+    traffic, window, weights, config and architecture modules."""
     import jax
 
     import check
     import drive
     import sut
     import weights
-    from repro.launch.compile_cache import use_compile_cache
 
     cell = load_cell(name, root)
     if devices is None:
         devices = device_check(cell["chips"])
-    print(f"compile cache: {use_compile_cache()}", file=sys.stderr)
+    print(f"compile cache: {sut.use_compile_cache()}", file=sys.stderr)
     # every program, however fast it compiles, is found again next run
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     clock = CompileClock()
     cfg, mix = cell["cfg"], cell["mix"]
-    ucfg = sut.unet_config(cfg)
+    model = plug.arch(cfg, root)
+    prog = sut.program(cfg, root)
+    mcfg = prog.model_config(cfg)
     seed = seed % (2 ** 63)
 
     phases = {"start": time.perf_counter() - T_START}
-    w = weights.make_weights(seed, cfg["n_planes"], cfg["block_reps"],
-                             cfg["input_features"], cfg["nClasses"])
-    params = sut.program_params(w, cfg["n_planes"], cfg["block_reps"])
+    w = weights.make_weights(seed, model.weight_shapes(cfg))
+    params = prog.params(w, cfg)
     phases["weights"] = time.perf_counter() - T_START
-    traffic = drive.traffic(mix, seed, seconds, ucfg)
+    traffic = drive.traffic(mix, seed, seconds, cfg)
     phases["traffic"] = time.perf_counter() - T_START
     specs = {} if session is None else session.setdefault("specs", {})
     key = (cell["config"], json.dumps(mix["pin"], sort_keys=True))
     if key not in specs:
-        specs[key] = sut.pin_spec(ucfg, drive.pin_rooms(mix, ucfg))
+        specs[key] = prog.pin_spec(mcfg, drive.pin_rooms(mix, cfg))
     spec = specs[key]
     phases["pin"] = time.perf_counter() - T_START
-    eng = sut.build_engine(ucfg, params, cfg["batch"], spec)
+    eng = prog.build_engine(mcfg, params, cfg["batch"], spec)
     if on_engine is not None:
         on_engine(eng)
     eng.serve_forever()
@@ -215,17 +217,18 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
 
     metrics = {}
     if trace:
+        import spans
         import trace_reduce
         import work
 
-        red = trace_reduce.reduce_file(trace_reduce.find_trace(tdir),
-                                       n_chips=cell["chips"])
+        red = spans.reduce_window(trace_reduce.find_trace(tdir),
+                                  n_chips=cell["chips"])
         shutil.rmtree(tdir, ignore_errors=True)
         pk = work.peaks(devices[0].device_kind)
         ctx = {"window": win, "done": done,
                "trace": red, "chips": cell["chips"], "peaks": pk,
-               "work": work.window_work(traffic, done, cfg,
-                                        sut.kernel_levels(spec), pk)}
+               "work": work.window_work(traffic, done, cfg, model,
+                                        prog.kernel_sites(spec), pk)}
         for m in cell["per_layer"]:
             v = reader(m["name"], root)(ctx)
             if v is not None:
@@ -237,7 +240,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
 
     traffic.release()
     gc.collect()
-    verdict = check.check(traffic, win, w, cfg)
+    verdict = check.check(traffic, win, w, cfg, model)
     print(f"checked {verdict['n_checked']} requests against the reference",
           file=sys.stderr)
     dev = devices[0]
@@ -255,7 +258,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         result["breakdown"] = red["breakdown"]
     result["check"] = verdict["numbers"]
     if session is not None:
-        session.update(traffic=traffic, window=win, weights=w, cfg=cfg)
+        session.update(traffic=traffic, window=win, weights=w, cfg=cfg,
+                       model=model, program=prog)
     return result
 
 

@@ -19,14 +19,15 @@ window (the first benchmark span to the end of the last), and gives:
   (``serve.plan_wait>plan.order``); with no program span over the gap, the
   benchmark's span, as ``trace_reduce`` labels it;
 * ``idle_plan_wait_pct``: the chip's idle time under ``serve.plan_wait``
-  over all its idle time in the window;
+  over all its idle time in the window (``None`` in a trace without the
+  program's spans);
 * ``plan_ms``: plan time per request by phase and level (``-`` for phases
   outside a level), and ``plan_cpu_pct``, the ``plan.request`` spans' CPU
   time over their wall time.
 
-``trace_reduce`` does not call this module, and ``run.py`` removes its
-trace after the reduction: PERF.md (Open questions) says what would join
-them.
+``reduce_window``, which ``run.py`` calls on a traced run, reads the
+trace once and gives ``trace_reduce``'s numbers with these beside them, the
+breakdown's idle gaps labelled by this rule.
 """
 from __future__ import annotations
 
@@ -52,14 +53,12 @@ def split_name(raw: str) -> tuple[str, dict]:
     return m.group(1), meta
 
 
-def program_spans(path: str) -> list[tuple]:
+def program_spans(pd) -> list[tuple]:
     """-> [(name, line, start_ns, end_ns, meta)] of the host planes'
-    ``serve.*`` and ``plan.*`` events; ``line`` (plane, index) tells the
-    threads apart."""
-    from jax.profiler import ProfileData
-
+    ``serve.*`` and ``plan.*`` events of a trace (``trace_reduce.load``);
+    ``line`` (plane, index) tells the threads apart."""
     out = []
-    for plane in ProfileData.from_file(path).planes:
+    for plane in pd.planes:
         if plane.name.startswith("/device:"):
             continue
         for li, line in enumerate(plane.lines):
@@ -164,13 +163,30 @@ def reduce_events(ops: dict, bench_spans: list, spans: list) -> dict:
     return {
         "idle_gaps": [[label(g, spans, bench_spans), (g[1] - g[0]) * 1e-9]
                       for g in top],
-        "idle_plan_wait_pct": 100.0 * under / idle if idle > 0 else None,
+        "idle_plan_wait_pct": (100.0 * under / idle if idle > 0 and spans
+                               else None),
         **plan_table(spans, lo, hi),
     }
 
 
 def reduce_file(path: str) -> dict:
-    return reduce_events(*tr._events(path), program_spans(path))
+    pd = tr.load(path)
+    return reduce_events(*tr._events(pd), program_spans(pd))
+
+
+def reduce_window(path: str, n_chips: int) -> dict:
+    """``trace_reduce.reduce_file``'s numbers, with the breakdown's idle
+    gaps labelled by the program's spans and ``idle_plan_wait_pct`` and the
+    plan table beside them, from one read of the trace."""
+    pd = tr.load(path)
+    ops, bench_spans = tr._events(pd)
+    if not ops:
+        raise ValueError(f"{path}: no device ops on a /device:TPU plane")
+    red = tr.reduce_events(ops, bench_spans, n_chips)
+    named = reduce_events(ops, bench_spans, program_spans(pd))
+    red["breakdown"]["idle_gaps"] = named.pop("idle_gaps")
+    red.update(named)
+    return red
 
 
 def main(argv=None) -> int:

@@ -24,6 +24,9 @@ def test_every_cell_resolves_to_its_files():
         cell = run.load_cell(w["name"])
         assert cell["cfg"]["name"] == w["config"]
         assert cell["mix"]["kind"] in ("rooms", "stream")
+        archs = run.ROOT / "bench" / "archs"
+        for side in ("", "_sut"):
+            assert (archs / f"{cell['cfg']['arch']}{side}.py").is_file()
         assert any(m["name"] == "setup_s" for m in cell["end_to_end"])
         assert len(cell["end_to_end"]) >= 2 and cell["per_layer"]
         for m in cell["per_layer"]:
@@ -31,14 +34,20 @@ def test_every_cell_resolves_to_its_files():
 
 
 def test_a_new_cell_runs_from_added_files(tiny_root, tmp_path, cpu_devices):
-    """A later PR adds a config, a mix and a workload entry; no file of the
-    harness changes."""
+    """A config, a mix, a workload entry and an architecture are added as
+    files; no file of the harness changes. The architecture is the SCN
+    U-Net's two modules under another name, which only the new config
+    names."""
     root = tmp_path / "checkout"
     shutil.copytree(tiny_root, root)
     before = {p: p.read_bytes() for p in (root / "bench").rglob("*")
               if p.is_file()}
+    archs = root / "bench" / "archs"
+    for side in ("", "_sut"):
+        shutil.copy(archs / f"scn_unet{side}.py",
+                    archs / f"scn_copy{side}.py")
     cfg = json.loads((root / "bench/configs/tiny.json").read_text())
-    cfg.update(name="tiny_reps2", block_reps=2)
+    cfg.update(name="tiny_reps2", block_reps=2, arch="scn_copy")
     (root / "bench/configs/tiny_reps2.json").write_text(json.dumps(cfg))
     mix = json.loads((root / "bench/traffic/rooms.json").read_text())
     mix.update(outstanding=2, objects=[3, 5])
@@ -52,12 +61,15 @@ def test_a_new_cell_runs_from_added_files(tiny_root, tmp_path, cpu_devices):
             m["workloads"].append("tiny2.pairs")
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
 
+    session = {}
     res = run.run_cell("tiny2.pairs", 77, 2.0, False, root=root,
-                       devices=cpu_devices)
+                       devices=cpu_devices, session=session)
     assert res["correct"] and res["attempted"] >= 2 and res["failed"] == 0
     assert set(res["metrics"]) == {"scenes_per_s", "setup_s"}
     assert list(res)[-1] == "check"
     assert all(before[p] == p.read_bytes() for p in before)
+    assert session["model"].__file__ == str(archs / "scn_copy.py")
+    assert session["program"].__file__ == str(archs / "scn_copy_sut.py")
 
 
 @pytest.mark.parametrize("cell", ["m16.rooms", "m32.rooms"])
@@ -71,15 +83,16 @@ def test_every_per_layer_metric_reads_in_its_cells(tiny_root, cpu_devices,
     session = {}
     run.run_cell(cell, 3, 1.5, False, root=tiny_root, devices=cpu_devices,
                  session=session)
-    win, cfg = session["window"], session["cfg"]
+    win, cfg, model = session["window"], session["cfg"], session["model"]
     done = [r for r in win.records if r.status == "completed"]
     pk = work.peaks("TPU v5 lite")
     cell_ = run.load_cell(cell, tiny_root)
     ctx = {"window": win, "done": done,
            "trace": {"busy_s": 0.5, "window_s": 2.0,
-                     "kernel_s": {"sspnna": 0.1}},
+                     "kernel_s": {"sspnna": 0.1}, "idle_plan_wait_pct": 97.0},
            "chips": 1, "peaks": pk,
-           "work": work.window_work(session["traffic"], done, cfg, [0], pk)}
+           "work": work.window_work(session["traffic"], done, cfg, model,
+                                    {("stem", 0), ("sub", 0)}, pk)}
     for m in cell_["per_layer"]:
         v = run.reader(m["name"], tiny_root)(ctx)
         assert v is not None and v >= 0, m["name"]

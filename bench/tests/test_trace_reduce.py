@@ -87,7 +87,7 @@ def test_a_recorded_chip_trace(tmp_path):
     assert len(modules) == 6 and len(waves) == 2
     assert r["busy_s"] == pytest.approx(
         sum(e.duration_ns for e in modules) * 1e-9, abs=2e-5)
-    ops, spans = tr._events(str(path))
+    ops, spans = tr._events(tr.load(str(path)))
     assert [n for n, _, _ in spans].count("bench.submit") == 2
     assert r["window_s"] == pytest.approx(5.004, abs=1e-3)
     # the fused kernel is most of each wave; fusions come next
@@ -100,3 +100,44 @@ def test_a_recorded_chip_trace(tmp_path):
     assert all(name == "bench.wait" for name, _ in gaps[:3])
     assert sum(d for _, d in gaps) <= r["window_s"] - r["busy_s"] + 1e-6
     assert gaps[0][1] + gaps[1][1] > 0.8 * (r["window_s"] - r["busy_s"])
+
+
+#: what the reduction read from the recorded trace before the breakdown's
+#: idle gaps were labelled by the program's spans
+RECORDED = {
+    "busy_s": 0.6441750810000001, "window_s": 5.004050047000001,
+    "device_ops": [
+        ["closed_call[tpu_custom_call]", 0.39336071899999997],
+        ["fusion", 0.21053621399999994], ["copy", 0.02272842099999999],
+        ["bitcast_dynamic-update-slice_fusion", 0.0027510359999999997],
+        ["dynamic-slice_bitcast_fusion", 0.0026387400000000005],
+        ["constant_dynamic-slice_fusion", 0.0022061050000000007],
+        ["pad_bitcast_fusion", 0.0015227140000000003],
+        ["reshape", 0.001260727],
+        ["multiply_reduce_fusion", 0.0011955270000000003],
+        ["select_maximum_fusion", 0.000773233]]}
+
+
+def test_the_recorded_trace_reads_as_before(tmp_path):
+    """``spans.reduce_window``, the reduction of a traced run, reads busy,
+    window and device ops exactly as ``reduce_file`` did, and labels the
+    same gaps."""
+    import lzma
+
+    import spans
+
+    path = tmp_path / "window.xplane.pb"
+    path.write_bytes(lzma.decompress(
+        (TESTDATA / "m16.stream.xplane.pb.xz").read_bytes()))
+    plain = tr.reduce_file(str(path), n_chips=1)
+    named = spans.reduce_window(str(path), n_chips=1)
+    for r in (plain, named):
+        assert r["busy_s"] == RECORDED["busy_s"]
+        assert r["window_s"] == RECORDED["window_s"]
+        assert r["breakdown"]["device_ops"] == RECORDED["device_ops"]
+    # recorded before the program had spans: the bench labels stand
+    got = named["breakdown"]["idle_gaps"]
+    want = plain["breakdown"]["idle_gaps"]
+    assert [n for n, _ in got] == [n for n, _ in want]
+    assert [d for _, d in got] == pytest.approx([d for _, d in want])
+    assert named["idle_plan_wait_pct"] is None

@@ -19,7 +19,8 @@ Which planes and lines hold what (read by hand from a TPU v5e trace):
 the window, averaged over the chips), the window's length, summed device
 time per op name and per kernel, collective time, and the ``breakdown``:
 the ten ops that took most time and the ten longest idle gaps of chip 0,
-each labelled by the benchmark span the host was in.
+each labelled by the benchmark span the host was in (``bench/spans.py``'s
+``reduce_window`` labels them by the program's spans).
 """
 from __future__ import annotations
 
@@ -37,12 +38,16 @@ _NAME = re.compile(r"%?([A-Za-z_][\w\-]*?)(?:\.\d+)*(?: =|$)")
 _TARGET = re.compile(r'custom_call_target="([^"]+)"')
 
 
-def _events(path: str):
-    """-> (device ops {chip: [(name, start, end)]}, host spans
-    [(name, start, end)]), times in ns."""
+def load(path: str):
+    """The trace of an ``.xplane.pb`` file, read once."""
     from jax.profiler import ProfileData
 
-    pd = ProfileData.from_file(path)
+    return ProfileData.from_file(path)
+
+
+def _events(pd):
+    """-> (device ops {chip: [(name, start, end)]}, host spans
+    [(name, start, end)]) of a trace, times in ns."""
     ops, spans = defaultdict(list), []
     for plane in pd.planes:
         m = re.match(r"/device:TPU:(\d+)$", plane.name)
@@ -168,7 +173,7 @@ def find_trace(tdir: str) -> str:
 
 
 def reduce_file(path: str, n_chips: int) -> dict:
-    ops, spans = _events(path)
+    ops, spans = _events(load(path))
     if not ops:
         raise ValueError(f"{path}: no device ops on a /device:TPU plane")
     return reduce_events(ops, spans, n_chips)
