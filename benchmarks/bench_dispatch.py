@@ -82,12 +82,13 @@ def _measured_case(table, name, res, cap, c, k):
     n_active = int(mask.sum())
     density = n_active / res**3
 
-    # analytical dispatch, exactly as _assemble_level derives it
+    # analytical dispatch, exactly as _assemble_site derives it
     attrs = spade.extract_attributes(
         np.asarray(coir.indices), mask, order.order)
-    layer = _layer_spec(name, n_active, c)
+    k = int(np.asarray(coir.indices).shape[1])
+    layer = _layer_spec(name, n_active, k, c, c)
     df = spade.explore(layer, {"CIRF": attrs, "CORF": attrs}, MEASURE_BUDGET)
-    analytical = dispatch_from_dataflow(df, attrs, n_active)
+    analytical = dispatch_from_dataflow(df, attrs, n_active, k)
     d_o = analytical.delta_o or 32
     d_i = analytical.delta_i or 123
 

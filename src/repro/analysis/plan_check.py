@@ -56,14 +56,23 @@ def check_coir(coir, n_in: int, path: str) -> list[Finding]:
             "REPRO-P001", f"{path}.indices",
             f"values span [{lo}, {hi}], outside [-1, {n_in})"))
     bm = getattr(coir, "bitmask", None)
-    if bm is not None and idx.shape[1] <= 32:
-        k = idx.shape[1]
-        want = ((idx >= 0).astype(np.uint32)
-                * (np.uint32(1) << np.arange(k, dtype=np.uint32))).sum(
-                    axis=1, dtype=np.uint64)
+    if bm is not None:
+        # plane k is bit k % 32 of header word k // 32
+        v, k = idx.shape
+        words = -(-k // 32)
+        valid = np.zeros((v, 32 * words), bool)
+        valid[:, :k] = idx >= 0
+        want = (valid.reshape(v, words, 32).astype(np.uint64)
+                << np.arange(32, dtype=np.uint64)).sum(axis=2,
+                                                       dtype=np.uint64)
         got = _np(bm).astype(np.uint64)
-        if got.shape == want.shape and not np.array_equal(got, want):
-            n_bad = int((got != want).sum())
+        shape = (v,) if words == 1 else (v, words)
+        if got.shape != shape:
+            out.append(Finding(
+                "REPRO-P001", f"{path}.bitmask",
+                f"expected shape {shape} for K={k}, got {got.shape}"))
+        elif not np.array_equal(got.reshape(v, words), want):
+            n_bad = int((got != want).any(axis=1).sum())
             out.append(Finding(
                 "REPRO-P001", f"{path}.bitmask",
                 f"bitmask disagrees with index holes on {n_bad} rows"))
@@ -197,6 +206,8 @@ def check_scene_plan(plan, path: str = "plan") -> list[Finding]:
             out.append(Finding("REPRO-P001", f"{p}.coords",
                                f"expected ({v}, 3), got {coords.shape}"))
         out.extend(_check_conv(lvl.sub, v, v, mask, f"{p}.sub"))
+        if getattr(lvl, "stem", None) is not None:
+            out.extend(_check_conv(lvl.stem, v, v, mask, f"{p}.stem"))
         if lvl.down is not None and li + 1 < len(levels):
             n_rows = int(_np(lvl.down.coir.indices).shape[0])
             n_in = sizes[li] if n_rows == sizes[li + 1] else sizes[li + 1]

@@ -10,7 +10,9 @@ Two flavors, exactly as in the paper:
 
 The paper stores variable-length index lists; for fixed-shape jit we store a
 dense ``(V, K)`` index block with -1 holes and keep the bitmask as the header
-word (the WAVES front-end consumes exactly this header). Logical
+word (the WAVES front-end consumes exactly this header). A kernel of more
+than 32 planes (a 5^3 stem has 125) takes ``ceil(K / 32)`` header words per
+row: plane k is bit ``k % 32`` of word ``k // 32``. Logical
 (variable-length) metadata sizes for bandwidth accounting are computed from
 the bitmask popcounts, so compression numbers match the paper's definition,
 not the padded layout.
@@ -34,7 +36,8 @@ class COIR(NamedTuple):
     """COIR metadata block (either flavor; flavor tracked by the caller).
 
     indices: (V, K) int32 — partner voxel index per weight plane, -1 absent.
-    bitmask: (V,) uint32  — bit k set iff indices[:, k] >= 0.
+    bitmask: (V,) uint32  — bit k set iff indices[:, k] >= 0; for K > 32,
+             (V, ceil(K / 32)) uint32, plane k at bit k % 32 of word k // 32.
     mask:    (V,)  bool   — active rows of the major point set.
     """
 
@@ -65,9 +68,13 @@ class COIR(NamedTuple):
 
 
 def _pack_bitmask(indices: jax.Array) -> jax.Array:
-    k = indices.shape[1]
-    bits = (indices >= 0).astype(jnp.uint32) << jnp.arange(k, dtype=jnp.uint32)[None, :]
-    return jnp.sum(bits, axis=1, dtype=jnp.uint32)
+    v, k = indices.shape
+    words = -(-k // 32)
+    valid = jnp.pad(indices >= 0, ((0, 0), (0, 32 * words - k)))
+    bits = (valid.reshape(v, words, 32).astype(jnp.uint32)
+            << jnp.arange(32, dtype=jnp.uint32))
+    packed = jnp.sum(bits, axis=2, dtype=jnp.uint32)
+    return packed[:, 0] if words == 1 else packed
 
 
 @functools.partial(jax.jit, static_argnames=("resolution", "stride"))

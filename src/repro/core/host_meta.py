@@ -85,10 +85,17 @@ def query_neighbors_np(
 
 
 def _pack_bitmask_np(indices: np.ndarray) -> np.ndarray:
-    k = indices.shape[1]
-    bits = ((indices >= 0).astype(np.uint32)
-            << np.arange(k, dtype=np.uint32)[None, :])
-    return bits.sum(axis=1, dtype=np.uint32)
+    """Numpy twin of ``coir._pack_bitmask``: one uint32 word per row, or
+    ``ceil(K / 32)`` words per row for K > 32 (plane k at bit k % 32 of
+    word k // 32)."""
+    v, k = indices.shape
+    words = -(-k // 32)
+    valid = np.zeros((v, 32 * words), bool)
+    valid[:, :k] = indices >= 0
+    bits = (valid.reshape(v, words, 32).astype(np.uint32)
+            << np.arange(32, dtype=np.uint32))
+    packed = bits.sum(axis=2, dtype=np.uint32)
+    return packed[:, 0] if words == 1 else packed
 
 
 def build_cirf_np(
@@ -249,9 +256,7 @@ def downsample_coords_np(
 # Streaming: delta-based incremental metadata for overlapping LiDAR frames
 # ---------------------------------------------------------------------------
 
-_OFFS3 = kernel_offsets(3)                   # centered 3^3 submanifold stencil
 _OFFS2 = kernel_offsets(2, centered=False)   # [0,2)^3 down/up pair stencil
-_K3 = _OFFS3.shape[0]                        # 27
 _K2 = _OFFS2.shape[0]                        # 8
 
 
@@ -424,6 +429,10 @@ class StreamMetaState:
         self.resolution = resolution
         self.capacity = capacity
         self.n_levels = n_levels
+        #: the levels' 3^3 submanifold stencil, which every table is patched
+        #: with; centred, so plane ``K - 1 - k`` is plane k's reciprocal
+        self.offsets = kernel_offsets(3)
+        self.k = len(self.offsets)
         self.n: list | None = None  # None until the first frame
 
     # -- full (re)build ----------------------------------------------------
@@ -450,7 +459,7 @@ class StreamMetaState:
         self.down = []
         self.up = []
         for li, (c, m, res) in enumerate(geo):
-            self.sub.append(build_cirf_np(c, m, c, m, _OFFS3, res))
+            self.sub.append(build_cirf_np(c, m, c, m, self.offsets, res))
             if li > 0:
                 self.keys.append(
                     linear_key_np(c[: self.n[li]], res))
@@ -566,7 +575,7 @@ class StreamMetaState:
             sub = self.sub[0]
             T = np.asarray(sub.indices).copy()
             bm = np.asarray(sub.bitmask).copy()
-            k_ar = np.arange(_K3, dtype=np.int32)
+            k_ar = np.arange(self.k, dtype=np.int32)
             touched = [rem, assigned]
             if R:
                 # drop reciprocal entries pointing at removed voxels
@@ -574,18 +583,18 @@ class StreamMetaState:
                 rvm = rv >= 0
                 jj = rv[rvm]
                 kk = np.broadcast_to(k_ar, rv.shape)[rvm]
-                T[jj, _K3 - 1 - kk] = -1
+                T[jj, self.k - 1 - kk] = -1
                 T[rem] = -1
                 touched.append(jj)
             if A:
-                probe = add_coords[:, None, :] + _OFFS3[None, :, :]
-                add_idx = self.grid.lookup(probe, np.ones((A, _K3), bool))
+                probe = add_coords[:, None, :] + self.offsets[None, :, :]
+                add_idx = self.grid.lookup(probe, np.ones((A, self.k), bool))
                 T[assigned] = add_idx
                 avm = add_idx >= 0
                 jj = add_idx[avm]
                 kk = np.broadcast_to(k_ar, add_idx.shape)[avm]
                 aa = np.broadcast_to(assigned[:, None], add_idx.shape)[avm]
-                T[jj, _K3 - 1 - kk] = aa
+                T[jj, self.k - 1 - kk] = aa
                 touched.append(jj)
             touched = np.unique(np.concatenate(
                 [np.asarray(t, np.int32) for t in touched]))
@@ -685,23 +694,23 @@ class StreamMetaState:
             # coarse submanifold table: gather kept rows, probe inserted
             if c_changed:
                 prev_T = np.asarray(self.sub[li].indices)
-                T = np.empty((cap, _K3), np.int32)
+                T = np.empty((cap, self.k), np.int32)
                 T[n_new:] = -1      # every row < n_new is kept or inserted
                 pv = prev_T[kept_prev_rows]
                 T[kept_new_rows] = np.where(
                     pv >= 0, c_remap[np.maximum(pv, 0)], -1)
                 if n_ins:
                     ins_coords = c_l[ins_new_rows]
-                    probe = ins_coords[:, None, :] + _OFFS3[None, :, :]
+                    probe = ins_coords[:, None, :] + self.offsets[None, :, :]
                     ins_idx = _prefix_lookup(new_keys, probe, r_l)
                     T[ins_new_rows] = ins_idx
-                    k_ar = np.arange(_K3, dtype=np.int32)
+                    k_ar = np.arange(self.k, dtype=np.int32)
                     ivm = ins_idx >= 0
                     jj = ins_idx[ivm]
                     kk = np.broadcast_to(k_ar, ins_idx.shape)[ivm]
                     aa = np.broadcast_to(
                         ins_new_rows[:, None], ins_idx.shape)[ivm]
-                    T[jj, _K3 - 1 - kk] = aa
+                    T[jj, self.k - 1 - kk] = aa
                 bm = np.zeros(cap, np.uint32)
                 bm[:n_new] = _pack_bitmask_np(T[:n_new])
                 self.sub[li] = COIR(T, bm, m_l)

@@ -30,7 +30,7 @@ from repro.sparse.tensor import SparseVoxelTensor
 
 class SparseConvParams(NamedTuple):
     weight: jax.Array  # (K, C, N)
-    bias: jax.Array    # (N,)
+    bias: jax.Array | None  # (N,); None for a bias-free conv
 
 
 def init_sparse_conv(
@@ -61,7 +61,31 @@ def reference_conv_cirf(
     out = jnp.einsum(
         "okc,kcn->on", g, params.weight, preferred_element_type=jnp.float32
     ).astype(feats_in.dtype)
-    out = out + params.bias.astype(out.dtype)
+    if params.bias is not None:
+        out = out + params.bias.astype(out.dtype)
+    return out * coir.mask[:, None].astype(out.dtype)
+
+
+def reference_conv_ws(
+    feats_in: jax.Array, coir: COIR, params: SparseConvParams
+) -> jax.Array:
+    """Out-major evaluation one weight plane at a time (weight-stationary):
+    per plane, gather its partners ``(V, C)`` and add their product into an
+    f32 sum, in plane order. It holds one plane's rows where
+    ``reference_conv_cirf`` holds all K: a TPU pads a row to 128 lanes, so
+    a 5^3 stem's ``(V, 125, 4)`` block would take 32x its size."""
+    def plane(acc, kw):
+        rows, ok, w = kw
+        g = jnp.where(ok[:, None], jnp.take(feats_in, rows, axis=0), 0)
+        return acc + jnp.dot(g, w, preferred_element_type=jnp.float32), None
+
+    v, n = coir.indices.shape[0], params.weight.shape[2]
+    out, _ = jax.lax.scan(
+        plane, jnp.zeros((v, n), jnp.float32),
+        (jnp.maximum(coir.indices, 0).T, coir.valid().T, params.weight))
+    out = out.astype(feats_in.dtype)
+    if params.bias is not None:
+        out = out + params.bias.astype(out.dtype)
     return out * coir.mask[:, None].astype(out.dtype)
 
 
@@ -82,14 +106,26 @@ def sparse_conv_cirf(
         backend="reference")
 
 
-def masked_batchnorm_relu(x, mask, scale, offset, eps: float = 1e-5):
-    """BN + ReLU over active rows only (the SCN conv-block epilogue)."""
+def _normalize(x, mask, scale, offset, eps):
+    """Batch norm over the active rows (biased variance) -> (y, row mask)."""
     m = mask[:, None].astype(x.dtype)
     n = jnp.maximum(jnp.sum(m), 1.0)
     mean = jnp.sum(x * m, axis=0) / n
     var = jnp.sum(jnp.square(x - mean) * m, axis=0) / n
-    y = (x - mean) * jax.lax.rsqrt(var + eps) * scale + offset
+    return (x - mean) * jax.lax.rsqrt(var + eps) * scale + offset, m
+
+
+def masked_batchnorm_relu(x, mask, scale, offset, eps: float = 1e-5):
+    """BN + ReLU over active rows only (the SCN conv-block epilogue)."""
+    y, m = _normalize(x, mask, scale, offset, eps)
     return jax.nn.relu(y) * m
+
+
+def masked_batchnorm(x, mask, scale, offset, eps: float = 1e-5):
+    """BN over active rows only, zero elsewhere (a residual branch's last
+    norm, before the add)."""
+    y, m = _normalize(x, mask, scale, offset, eps)
+    return y * m
 
 
 def sparse_conv_corf(

@@ -41,7 +41,7 @@ from __future__ import annotations
 import time
 
 from repro.analysis.runtime import ordered_rlock
-from repro.core.sparse_conv import reference_conv_cirf
+from repro.core.sparse_conv import reference_conv_cirf, reference_conv_ws
 from repro.engine.plan import REFERENCE, SSPNNA, ConvPlan
 from repro.kernels.sspnna.ops import run_sspnna_conv
 
@@ -382,12 +382,17 @@ class BackendRegistry:
 
 class ReferenceBackend(Backend):
     """Gather + one fused einsum over all weight planes — the coarse M-V
-    dispatch and the numerical oracle (``core.sparse_conv``)."""
+    dispatch and the numerical oracle (``core.sparse_conv``). A plan the
+    planner sent here on a weight-stationary walk (``"WS"``: a site SPADE
+    tiled for the kernel, which the kernel's VMEM cannot hold) walks the
+    planes one at a time instead."""
 
     name = REFERENCE
 
     def run(self, x, params, plan: ConvPlan, *, ctx, **kw):
         del ctx, kw  # kernel knobs don't apply to the einsum path
+        if plan.dispatch.backend == REFERENCE and plan.dispatch.walk == "WS":
+            return reference_conv_ws(x, plan.coir, params)
         return reference_conv_cirf(x, plan.coir, params)
 
 
@@ -411,7 +416,9 @@ class SSpNNABackend(Backend):
             pair_counts=plan.tiles.pair_counts,
             use_kernel=use_kernel, interpret=interpret,
             block_n=block_n or (plan.dispatch.block_n or None))
-        out = raw.astype(x.dtype) + params.bias.astype(x.dtype)
+        out = raw.astype(x.dtype)
+        if params.bias is not None:
+            out = out + params.bias.astype(x.dtype)
         return out * plan.coir.mask[:, None].astype(out.dtype)
 
 

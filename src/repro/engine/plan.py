@@ -25,13 +25,14 @@ import hashlib
 import threading
 import time
 from collections import OrderedDict
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis.hlo_gates import gate_vmem_budget
 from repro.core import spade
 from repro.core.coir import COIR
 from repro.core.hashgrid import kernel_offsets
@@ -45,12 +46,11 @@ from repro.core.soar import raster_order, soar_order
 from repro.analysis.runtime import ordered_condition, ordered_lock
 from repro.analysis.spans import span
 from repro.core.tiles import build_tile_plan, dma_tile_tables, max_tiles
+from repro.kernels.sspnna.sspnna import allocated_widths
 from repro.sparse.tensor import SparseVoxelTensor
 
 REFERENCE = "reference"
 SSPNNA = "sspnna"
-
-_K_SUB = 27  # submanifold 3^3 kernel volume
 
 # Layout version of the plan's array leaves; mixed into every PlanCache key
 # so cached plans from an older table layout can never be served to a kernel
@@ -59,8 +59,10 @@ _K_SUB = 27  # submanifold 3^3 kernel volume
 # carry the execution topology (mesh axes + shard layout), so a plan built
 # for one mesh can never be served to another. v4: plan builds may consult
 # circuit breakers (``breakers=`` build_kw, whose repr carries the board
-# generation) and reroute dispatch away from tripped backends.
-_PLAN_VERSION = 4
+# generation) and reroute dispatch away from tripped backends. v5: level 0
+# may carry a stem site of its own kernel (``LevelPlan.stem``), and a COIR of
+# more than 32 planes packs several bitmask words per row.
+_PLAN_VERSION = 5
 
 
 def _fault_injector():
@@ -119,13 +121,16 @@ class ConvPlan:
 
 
 class LevelPlan(NamedTuple):
-    """One U-Net level: active set + its three conv sites."""
+    """One U-Net level: active set + its conv sites."""
 
     coords: jax.Array
     mask: jax.Array
     sub: ConvPlan           # submanifold 3^3 metadata at this level
     down: ConvPlan | None   # strided 2^3 s2 conv to the next level
     up: ConvPlan | None     # transposed conv back to this level
+    #: level 0 only: a stem whose kernel differs from the level's 3^3
+    #: (MinkUNet's 5^3); None where the stem reads ``sub`` (SCN)
+    stem: ConvPlan | None = None
 
 
 @jax.tree_util.register_pytree_node_class
@@ -158,9 +163,12 @@ class ScenePlan:
 @dataclass(frozen=True)
 class PlanSpec:
     """Pinned per-level dispatch decisions: every plan built from one spec
-    has the same treedef and static shapes (one jit signature)."""
+    has the same treedef and static shapes (one jit signature). ``stem`` is
+    the stem site's decision where the config's stem has a plan of its own
+    (``stem_site``), else None."""
 
     levels: tuple[Dispatch, ...]
+    stem: Dispatch | None = None
 
 
 @dataclass(frozen=True)
@@ -474,6 +482,16 @@ def level_geometry(t: SparseVoxelTensor, cfg) -> list[tuple]:
     return out
 
 
+def stem_site(cfg) -> tuple[int, int, int] | None:
+    """``(kernel size, C_in, C_out)`` of the config's stem where it has a
+    plan of its own: a stem kernel other than the levels' 3^3 (MinkUNet's
+    5^3 ``conv0p1s1``). None where the stem reads level 0's submanifold
+    plan, as SCN's 3^3 stem does."""
+    if cfg.stem_kernel == 3:
+        return None
+    return cfg.stem_kernel, cfg.in_channels, cfg.stem_width
+
+
 def _order_rows(sub_coir: COIR, coords, mask, how: str, chunk: int) -> np.ndarray:
     """Ordering of active rows for tiling: SOAR (paper), raster, or active
     (occupancy order, cheapest)."""
@@ -491,7 +509,7 @@ def dispatch_from_dataflow(
     df: spade.Dataflow,
     attrs: spade.SparsityAttributes,
     n_majors: int,
-    kernel_volume: int = _K_SUB,
+    kernel_volume: int,
     n_tiles: int | None = None,
 ) -> Dispatch:
     """Map a SPADE dataflow onto an engine backend decision.
@@ -513,8 +531,35 @@ def dispatch_from_dataflow(
                     n_tiles if n_tiles is not None else 0)
 
 
-def _layer_spec(name: str, v: int, c: int) -> spade.LayerSpec:
-    return spade.LayerSpec(name, v, v, _K_SUB, c, c, 2)
+def _layer_spec(name: str, v: int, k: int, c_in: int,
+                c_out: int) -> spade.LayerSpec:
+    return spade.LayerSpec(name, v, v, k, c_in, c_out, 2)
+
+
+def _explore(name: str, attrs, n_majors: int, k: int, c_in: int, c_out: int,
+             mem_budget: int) -> tuple[Dispatch, spade.Dataflow]:
+    """SPADE's sweep for one conv site, mapped onto a backend."""
+    df = spade.explore(_layer_spec(name, n_majors, k, c_in, c_out),
+                       {"CIRF": attrs, "CORF": attrs}, mem_budget)
+    return dispatch_from_dataflow(df, attrs, n_majors, k), df
+
+
+def _fits_vmem(d: Dispatch, c_in: int, c_out: int, k: int) -> bool:
+    """Whether a fused-kernel dispatch passes REPRO-H003
+    (``analysis.hlo_gates``) at the widths the kernel allocates: channels
+    padded to whole lane tiles and the N-block ``block_n`` resolves to
+    (``kernels.sspnna.allocated_widths``). A 5^3 stem from 4 to 32
+    channels alone holds a 125 x 128 x 128 float32 weight slab,
+    double-buffered."""
+    c_p, bn = allocated_widths(c_in, c_out, d.block_n or None)
+    return not gate_vmem_budget(replace(d, block_n=bn), c_p, k=k)
+
+
+def _kernel_refused(d: Dispatch) -> Dispatch:
+    """The reference dispatch of a site the kernel cannot hold: it keeps
+    SPADE's walk, so a weight-stationary site runs the reference one plane
+    at a time (``ReferenceBackend``)."""
+    return Dispatch(REFERENCE, d.flavor, d.walk)
 
 
 def build_plan_spec(
@@ -536,6 +581,12 @@ def build_plan_spec(
     capped at ``tile_margin`` times the worst observed count, so per-scene
     plans keep their static shapes without drowning in padding tiles.
 
+    A level's 3^3 convs are sized at ``cfg.widths[li]``, its widest block
+    width; a stem with a plan of its own (``stem_site``) is a site of its
+    own, its rows in level 0's order. A site pinned to the fused kernel
+    whose VMEM fails REPRO-H003 (``_fits_vmem``) is pinned to the
+    reference backend instead, on SPADE's walk (``_kernel_refused``).
+
     ``tune_block_n`` is an optional ``(c_in, n_out, delta_o, delta_i) -> int``
     hook (e.g. ``repro.engine.autotune.autotune_block_n``) that picks the
     fused kernel's N-block per layer signature; the choice is pinned in each
@@ -548,53 +599,61 @@ def build_plan_spec(
     table has one, and left untouched (miss recorded) when it doesn't — a
     cold table reproduces the analytical spec bitwise.
     """
-    offs3 = kernel_offsets(3)
     n_levels = len(cfg.widths)
-    per_level: list[list[spade.SparsityAttributes]] = [[] for _ in range(n_levels)]
-    observed_tiles: list[int] = [0] * n_levels
-    level_density: list[float] = [0.0] * n_levels
+    stem = stem_site(cfg)
+    # (kernel size, C_in, C_out) of each site: the levels, then the stem
+    sites = [(3, w, w) for w in cfg.widths] + ([stem] if stem else [])
+    per_site: list[list[spade.SparsityAttributes]] = [[] for _ in sites]
+    site_density: list[float] = [0.0] * len(sites)
     geo_attrs = []
     for t in scenes:
+        geometry = level_geometry(t, cfg)
         rows = []
-        for li, (coords, mask, res) in enumerate(level_geometry(t, cfg)):
-            coir = build_cirf_np(coords, mask, coords, mask, offs3, res)
-            ordering = _order_rows(coir, coords, mask, order, soar_chunk)
-            attrs = spade.extract_attributes(
-                np.asarray(coir.indices), np.asarray(mask), ordering)
-            per_level[li].append(attrs)
-            level_density[li] += (float(np.asarray(mask).sum())
-                                  / float(max(res, 1)) ** 3 / len(scenes))
-            rows.append((coir, ordering))
+        for coords, mask, res in geometry:
+            coir = build_cirf_np(coords, mask, coords, mask,
+                                 kernel_offsets(3), res)
+            rows.append((coir, _order_rows(coir, coords, mask, order,
+                                           soar_chunk), mask, res))
+        if stem is not None:
+            coords, mask, res = geometry[0]
+            rows.append((build_cirf_np(coords, mask, coords, mask,
+                                       kernel_offsets(stem[0]), res),
+                         rows[0][1], mask, res))
+        for si, (coir, ordering, mask, res) in enumerate(rows):
+            per_site[si].append(spade.extract_attributes(
+                np.asarray(coir.indices), np.asarray(mask), ordering))
+            site_density[si] += (float(np.asarray(mask).sum())
+                                 / float(max(res, 1)) ** 3 / len(scenes))
         geo_attrs.append(rows)
 
     dispatches = []
-    for li in range(n_levels):
-        msa = spade.meta_attributes(per_level[li])
-        layer = _layer_spec(f"level{li}", cfg.capacity, cfg.widths[li])
-        df = spade.explore(layer, {"CIRF": msa, "CORF": msa}, mem_budget)
-        d = dispatch_from_dataflow(df, msa, cfg.capacity)
+    for si, (size, c_in, c_out) in enumerate(sites):
+        k = size ** 3
+        msa = spade.meta_attributes(per_site[si])
+        d, _ = _explore(f"level{si}" if si < n_levels else "stem", msa,
+                        cfg.capacity, k, c_in, c_out, mem_budget)
         if autotune is not None:
             d = autotune.adjust_dispatch(
                 d, n_in=cfg.capacity, n_out=cfg.capacity,
-                c_in=cfg.widths[li], c_out=cfg.widths[li],
-                density=level_density[li], kernel_volume=_K_SUB)
+                c_in=c_in, c_out=c_out,
+                density=site_density[si], kernel_volume=k)
         if d.backend == SSPNNA:
             # worst observed budgeted tile count across the rep scenes
-            for rows in geo_attrs:
-                coir, ordering = rows[li]
-                tp = build_tile_plan(
-                    np.asarray(coir.indices), ordering, d.delta_o, d.delta_i)
-                observed_tiles[li] = max(observed_tiles[li], tp.n_tiles)
-            bound = max_tiles(cfg.capacity, d.delta_o, d.delta_i, _K_SUB)
-            n_tiles = min(bound,
-                          int(np.ceil(tile_margin * observed_tiles[li])) + 2)
-            block_n = (int(tune_block_n(cfg.widths[li], cfg.widths[li],
-                                        d.delta_o, d.delta_i))
+            observed = max(
+                build_tile_plan(np.asarray(rows[si][0].indices),
+                                rows[si][1], d.delta_o, d.delta_i).n_tiles
+                for rows in geo_attrs)
+            bound = max_tiles(cfg.capacity, d.delta_o, d.delta_i, k)
+            n_tiles = min(bound, int(np.ceil(tile_margin * observed)) + 2)
+            block_n = (int(tune_block_n(c_in, c_out, d.delta_o, d.delta_i))
                        if tune_block_n is not None else d.block_n)
             d = Dispatch(d.backend, d.flavor, d.walk, d.delta_o, d.delta_i,
                          n_tiles, block_n)
+            if not _fits_vmem(d, c_in, c_out, k):
+                d = _kernel_refused(d)
         dispatches.append(d)
-    return PlanSpec(tuple(dispatches))
+    return PlanSpec(tuple(dispatches[:n_levels]),
+                    dispatches[n_levels] if stem is not None else None)
 
 
 def _tile_arrays(cirf_indices, ordering, dispatch: Dispatch,
@@ -729,15 +788,23 @@ def _build_scene_plan(
     autotune=None,
     breakers=None,
 ) -> ScenePlan:
-    if spec is not None and len(spec.levels) != len(cfg.widths):
+    stem = stem_site(cfg)
+    if spec is not None and (len(spec.levels) != len(cfg.widths)
+                             or (spec.stem is None) != (stem is None)):
         raise ValueError(
-            f"spec has {len(spec.levels)} levels but cfg has "
-            f"{len(cfg.widths)} — was it built from another config?")
+            f"spec has {len(spec.levels)} levels and "
+            f"{'a' if spec.stem is not None else 'no'} stem site but cfg "
+            f"has {len(cfg.widths)} and {'a' if stem else 'no'} stem site "
+            "— was it built from another config?")
     offs2 = kernel_offsets(2, centered=False)
     offs3 = kernel_offsets(3)
     geometry = level_geometry(t, cfg)
+    site_kw = dict(plan_tiles=plan_tiles, mem_budget=mem_budget, order=order,
+                   soar_chunk=soar_chunk, autotune=autotune,
+                   breakers=breakers)
     levels: list[LevelPlan] = []
     stats: list[dict] = []
+    ordering0 = None
     for li, (coords, mask, res) in enumerate(geometry):
         with span("plan.level", level=li):
             down = up = None
@@ -753,64 +820,101 @@ def _build_scene_plan(
                     down = ConvPlan(down_coir)
                     up = ConvPlan(up_coir)
 
-            sub, info = _assemble_level(
-                sub_coir, coords, mask, li, cfg, spec=spec,
-                plan_tiles=plan_tiles, mem_budget=mem_budget, order=order,
-                soar_chunk=soar_chunk, autotune=autotune, breakers=breakers)
+            w = cfg.widths[li]
+            sub, info, ordering = _assemble_site(
+                sub_coir, coords, mask, li, w, w, cfg.resolution,
+                pinned=spec.levels[li] if spec is not None else None,
+                **site_kw)
+        if li == 0:
+            ordering0 = ordering
         stats.append(info)
         levels.append(LevelPlan(coords, mask, sub, down, up))
+    if stem is not None:
+        size, c_in, c_out = stem
+        coords, mask, res = geometry[0]
+        with span("plan.stem", k=size ** 3, rows=int(np.asarray(mask).sum())):
+            with span("plan.cirf"):
+                stem_coir = build_cirf_np(coords, mask, coords, mask,
+                                          kernel_offsets(size), res)
+            # the stem's rows keep level 0's order (its 3^3 adjacency)
+            stem_plan, info, _ = _assemble_site(
+                stem_coir, coords, mask, 0, c_in, c_out, cfg.resolution,
+                pinned=spec.stem if spec is not None else None,
+                ordering=ordering0, order_coir=levels[0].sub.coir, **site_kw)
+        info["site"] = "stem"
+        stats.append(info)
+        levels[0] = levels[0]._replace(stem=stem_plan)
     return ScenePlan(tuple(levels), stats)
 
 
-def _assemble_level(
-    sub_coir: COIR,
+def _assemble_site(
+    coir: COIR,
     coords,
     mask,
     li: int,
-    cfg,
+    c_in: int,
+    c_out: int,
+    resolution: int,
     *,
-    spec: PlanSpec | None,
+    pinned: Dispatch | None,
     plan_tiles: bool,
     mem_budget: int,
     order: str,
     soar_chunk: int,
     autotune=None,
     breakers=None,
-) -> tuple[ConvPlan, dict]:
-    """Dispatch/ordering/tile assembly for one level's submanifold conv.
+    ordering=None,
+    order_coir: COIR | None = None,
+) -> tuple[ConvPlan, dict, np.ndarray | None]:
+    """Dispatch/ordering/tile assembly for one stride-1 conv site at level
+    ``li`` from ``c_in`` to ``c_out`` channels -> (plan, stats, the row
+    ordering if one was computed). The kernel volume is the COIR's.
+    ``pinned`` is the spec's decision (None: adaptive SPADE on this scene).
+    Rows are ordered from ``order_coir``'s adjacency (default: ``coir``)
+    unless ``ordering`` is given.
 
-    Deterministic in ``(sub_coir, coords, mask)`` for a fixed ``autotune``
+    Deterministic in ``(coir, coords, mask)`` for a fixed ``autotune``
     table state — the streaming planner relies on this: running it on a
     patched (bitwise-equal) COIR yields bitwise-equal orderings, tiles and
     dispatch decisions.
     """
+    k = int(np.asarray(coir.indices).shape[1])
     n_active = int(np.asarray(mask).sum())
     info: dict = {"level": li, "n_active": n_active}
     dispatch = REFERENCE_DISPATCH
     tiles = None
+
+    def rows_in_order():
+        if ordering is not None:
+            return ordering
+        return _order_rows(order_coir if order_coir is not None else coir,
+                           coords, mask, order, soar_chunk)
+
     if plan_tiles and n_active > 0:
-        if spec is not None:
-            dispatch = spec.levels[li]
+        if pinned is not None:
+            dispatch = pinned
         else:
-            ordering = _order_rows(sub_coir, coords, mask, order, soar_chunk)
+            ordering = rows_in_order()
             with span("plan.spade"):
                 attrs = spade.extract_attributes(
-                    np.asarray(sub_coir.indices), np.asarray(mask), ordering)
-                layer = _layer_spec(f"level{li}", n_active, cfg.widths[li])
-                df = spade.explore(layer, {"CIRF": attrs, "CORF": attrs},
-                                   mem_budget)
-                dispatch = dispatch_from_dataflow(df, attrs, n_active)
+                    np.asarray(coir.indices), np.asarray(mask), ordering)
+                dispatch, df = _explore(f"level{li}", attrs, n_active, k,
+                                        c_in, c_out, mem_budget)
             info["arf"] = float(attrs.arf_avg[0])
             info["da_elems"] = df.da_elems
             if autotune is not None:
                 # measured-winner consult; a miss (recorded) keeps the
                 # analytical decision bitwise-unchanged
-                res3 = float(max(cfg.resolution >> li, 1)) ** 3
+                res3 = float(max(resolution >> li, 1)) ** 3
                 dispatch = autotune.adjust_dispatch(
                     dispatch, n_in=n_active, n_out=n_active,
-                    c_in=cfg.widths[li], c_out=cfg.widths[li],
-                    density=n_active / res3, kernel_volume=_K_SUB)
+                    c_in=c_in, c_out=c_out,
+                    density=n_active / res3, kernel_volume=k)
                 info["autotuned"] = dispatch.backend
+            if dispatch.backend == SSPNNA and not _fits_vmem(
+                    dispatch, c_in, c_out, k):
+                info["vmem_refused"] = True
+                dispatch = _kernel_refused(dispatch)
         if breakers is not None and dispatch.backend != REFERENCE:
             # circuit-breaker consult: a tripped backend routes new plans
             # along its fallback chain. This happens at *build* time (not
@@ -825,10 +929,8 @@ def _assemble_level(
                                           dispatch.delta_i, dispatch.n_tiles,
                                           dispatch.block_n))
         if dispatch.backend == SSPNNA:
-            if spec is not None:
-                ordering = _order_rows(sub_coir, coords, mask, order,
-                                       soar_chunk)
-            tiles = _tile_arrays(sub_coir.indices, ordering, dispatch,
+            ordering = rows_in_order()
+            tiles = _tile_arrays(coir.indices, ordering, dispatch,
                                  int(np.asarray(mask).shape[0]))
             if tiles is None:  # tile budget overflow: coarse dispatch
                 info["tile_overflow"] = True
@@ -846,7 +948,7 @@ def _assemble_level(
                         dispatch.delta_o, dispatch.delta_i,
                         int(tiles.out_rows.shape[0]), dispatch.block_n)
     info["dispatch"] = dispatch
-    return ConvPlan(sub_coir, tiles, dispatch), info
+    return ConvPlan(coir, tiles, dispatch), info, ordering
 
 
 # ---------------------------------------------------------------------------
@@ -885,6 +987,10 @@ class StreamPlanState:
                  soar_chunk: int = 512, min_overlap: float = 0.5,
                  stream_id: str | None = None, topology: str | None = None,
                  wait_s: float = 5.0):
+        if stem_site(cfg) is not None:
+            raise ValueError(
+                "stream plans patch the levels' 3^3 tables only; a config "
+                "whose stem has a plan of its own is served whole scenes")
         self.cfg = cfg
         self.cache = cache if cache is not None else PlanCache()
         self.spec = spec
@@ -986,8 +1092,11 @@ class StreamPlanState:
                 sub = prev.levels[li].sub
                 info = dict(prev.stats[li]) if prev.stats else {"level": li}
             else:
-                sub, info = _assemble_level(
-                    sub_coir, coords, mask, li, self.cfg, spec=self.spec,
+                w = self.cfg.widths[li]
+                sub, info, _ = _assemble_site(
+                    sub_coir, coords, mask, li, w, w, self.cfg.resolution,
+                    pinned=(self.spec.levels[li] if self.spec is not None
+                            else None),
                     plan_tiles=self.plan_tiles, mem_budget=self.mem_budget,
                     order=self.order, soar_chunk=self.soar_chunk)
             down = up = None

@@ -313,13 +313,34 @@ def _sharded_conv(x, cp: ShardedConvPlan, params, axis: str):
     return out * cp.mask[:, None].astype(out.dtype)
 
 
+def _scn_walk_only(params) -> None:
+    """Refuse params of any block structure but SCN's U-Net (plain conv
+    blocks, no norm after the stem or the down/up convs, biased convs): the
+    sharded walk below knows no other, and would compute a wrong one."""
+    levels = params["levels"]
+    convs = [params["stem"]] + [lvl[k] for lvl in levels
+                                for k in ("down", "up") if k in lvl]
+    extra = ("stem_bn" in params
+             or any(k in lvl for lvl in levels for k in ("down_bn", "up_bn"))
+             or any("conv" not in blk for lvl in levels
+                    for blk in lvl["enc"] + lvl.get("dec", []))
+             or any(c.bias is None for c in convs))
+    if extra:
+        raise ValueError(
+            "sharded scenes run the SCN U-Net walk only (plain conv "
+            "blocks, biased convs, no norm after the stem or the down/up "
+            "convs); these params describe another U-Net")
+
+
 def _local_apply_unet(params, x, levels, layout: ShardLayout):
     """Per-shard U-Net forward: (Vs, C_in) -> (Vs, n_classes).
 
     Valid under ``shard_map`` over ``layout.axis`` *or* under
     ``vmap(axis_name=layout.axis)`` — the latter is the single-device
-    reference path the mesh execution is bitwise-matched against.
+    reference path the mesh execution is bitwise-matched against. SCN's
+    walk only (``_scn_walk_only``).
     """
+    _scn_walk_only(params)
     axis, chunk = layout.axis, layout.bn_chunk
 
     def block(x, lvl_mask, cp, bp):

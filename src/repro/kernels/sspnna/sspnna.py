@@ -76,6 +76,15 @@ def _lane_block(n_p: int, block_n: int | None) -> int:
     return bn if n_p % bn == 0 else n_p
 
 
+def allocated_widths(c_in: int, c_out: int,
+                     block_n: int | None = None) -> tuple[int, int]:
+    """``(Cp, dN)`` the fused kernel allocates VMEM for, for a ``C_in ->
+    C_out`` conv: the input width padded to whole lane tiles, and the
+    N-block ``block_n`` resolves to over the padded output width."""
+    return _round_up(c_in, LANES), _lane_block(_round_up(c_out, LANES),
+                                               block_n)
+
+
 def _pad_channels(feats, weights):
     """Zero-pad the channel axes to whole lane tiles: feats ``(..., C)``,
     weights ``(K, C, N)``."""

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import warnings
 from dataclasses import dataclass
-from typing import Any, NamedTuple
+from typing import Any, ClassVar, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -38,9 +38,18 @@ class UNetConfig:
     capacity: int = 8192
     dtype: Any = jnp.float32
 
+    #: the stem is a 3^3 conv on level 0's submanifold plan
+    stem_kernel: ClassVar[int] = 3
+    #: the decoder concatenates [skip, upsampled]
+    concat: ClassVar[str] = "skip_up"
+
     @property
     def n_levels(self) -> int:
         return len(self.widths)
+
+    @property
+    def stem_width(self) -> int:
+        return self.widths[0]
 
 
 class LevelMeta(NamedTuple):

@@ -299,11 +299,12 @@ class SceneEngine(ServingBase):
             faults=faults,
             on_wave_error=self._on_wave_error)
 
+        concat = cfg.concat  # the decoder's order (SCN: [skip, up])
         if layout is not None:
             def sharded_apply(params, feats, plan):
                 return engine_api.apply_unet(
-                    params, feats, plan, backend=backend, ctx=ctx,
-                    use_kernel=use_kernel, interpret=interpret)
+                    params, feats, plan, concat=concat, backend=backend,
+                    ctx=ctx, use_kernel=use_kernel, interpret=interpret)
 
             self._apply = jax.jit(sharded_apply)
         else:
@@ -316,8 +317,8 @@ class SceneEngine(ServingBase):
                 batch_plan = jax.tree.map(lambda *xs: jnp.stack(xs), *plans)
                 return jax.vmap(
                     lambda f, p: engine_api.apply_unet(
-                        params, f, p, backend=backend, ctx=ctx,
-                        use_kernel=use_kernel, interpret=interpret)
+                        params, f, p, concat=concat, backend=backend,
+                        ctx=ctx, use_kernel=use_kernel, interpret=interpret)
                 )(batch_feats, batch_plan)
 
             self._apply = jax.jit(batched_apply)

@@ -17,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.sspnna.sspnna import sspnna_fused
 
 V = 65536  # voxel capacity of a served indoor scene
+V_2CM = 131072  # MinkUNet34C's: a 128k-voxel room at 2 cm
 
 
 @pytest.fixture(scope="module")
@@ -35,17 +36,17 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _conv_args(sharding, c, n, k, t, d_o, d_i, batch=()):
+def _conv_args(sharding, c, n, k, t, d_o, d_i, batch=(), v=V):
     def sds(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(batch + shape, dtype, sharding=sharding)
 
-    return (sds((V, c)), sds((k, c, n)), sds((t, d_o), jnp.int32),
+    return (sds((v, c)), sds((k, c, n)), sds((t, d_o), jnp.int32),
             sds((t, d_i), jnp.int32), sds((t, d_o, k), jnp.int32),
             sds((t,), jnp.int32))
 
 
 def _fused(*args):
-    return sspnna_fused(*args, n_out=V, interpret=False)
+    return sspnna_fused(*args, n_out=args[0].shape[-2], interpret=False)
 
 
 # (c_in, c_out, tile dO, tile dI, tiles): the stem at the level-0 tile
@@ -73,4 +74,20 @@ def test_batched_fused_kernel_compiles_for_v5e(one_chip):
     table is batched, which jax lowers as a loop of kernel calls."""
     args = _conv_args(one_chip, 16, 16, 27, 64, 32, 148, batch=(2,))
     hlo = jax.jit(jax.vmap(_fused)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+# MinkUNet34C at 2 cm, at the tiles SPADE pins for a 128k-voxel room: the
+# widest decoder conv (level 3, a 384-wide concat to 256) and level 0's
+# first decoder conv (128 -> 96), whose grid holds 8194 tiles
+MINK_SITES = [
+    pytest.param(384, 256, 32, 144, 364, id="level3-384to256"),
+    pytest.param(128, 96, 32, 142, 8194, id="level0-128to96"),
+]
+
+
+@pytest.mark.parametrize("c,n,d_o,d_i,t", MINK_SITES)
+def test_fused_kernel_compiles_at_2cm_capacity(one_chip, c, n, d_o, d_i, t):
+    args = _conv_args(one_chip, c, n, 27, t, d_o, d_i, v=V_2CM)
+    hlo = jax.jit(_fused).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo
