@@ -15,7 +15,9 @@ the thread waits for the GIL there.
 Spans nest per thread. A span inherits ``rid`` and ``wave`` from the span
 it runs in, and every enclosing span sums the wall time of the spans inside
 it by name (``Span.phase_ms``): a request's ``plan.request`` span carries
-its plan phases to ``WaveStats`` without a counter per phase. Spans mark
+its plan phases to ``WaveStats`` without a counter per phase. Code under a
+span adds to the innermost one's counters with ``count(**n)``
+(``Span.counts``, summed; trace stats on exit). Spans mark
 stages and phases, never a voxel, tile or loop iteration: off the profiler
 one costs a few microseconds.
 """
@@ -44,13 +46,14 @@ class Span:
     ``start_ms``/``end_ms`` are on the ``time.perf_counter`` clock."""
 
     __slots__ = ("name", "meta", "start_ms", "end_ms", "cpu_ms", "phase_ms",
-                 "_tm", "_cpu0")
+                 "counts", "_tm", "_cpu0")
 
     def __init__(self, name: str, meta: dict):
         self.name = name
         self.meta = {k: v for k, v in meta.items() if v is not None}
         self.start_ms = self.end_ms = self.cpu_ms = 0.0
         self.phase_ms: dict[str, float] = {}
+        self.counts: dict[str, int] = {}
 
     @property
     def wall_ms(self) -> float:
@@ -77,13 +80,24 @@ class Span:
     def __exit__(self, *exc) -> None:
         self.end_ms = time.perf_counter() * 1e3
         self.cpu_ms = (time.thread_time() - self._cpu0) * 1e3
-        self._tm.set_metadata(wall_ms=self.wall_ms, cpu_ms=self.cpu_ms)
+        self._tm.set_metadata(wall_ms=self.wall_ms, cpu_ms=self.cpu_ms,
+                              **self.counts)
         self._tm.__exit__(*exc)
         stack = _stack()
         stack.pop()
         for outer in stack:
             outer.phase_ms[self.name] = (outer.phase_ms.get(self.name, 0.0)
                                          + self.wall_ms)
+
+
+def count(**n: int) -> None:
+    """Add ``n`` to the counters of this thread's innermost open span;
+    outside any span, nothing."""
+    stack = _stack()
+    if stack:
+        counts = stack[-1].counts
+        for k, v in n.items():
+            counts[k] = counts.get(k, 0) + int(v)
 
 
 def span(name: str, **meta) -> Span:

@@ -134,8 +134,8 @@ def downsample_coords(
 class UpdatableSortedGrid:
     """Updatable sorted-key index: the streaming seam of the AdMAC search.
 
-    ``SortedGrid`` / ``host_meta.SortedGridNp`` re-sort the full key set per
-    scene — fine for i.i.d. uploads, wasteful for a 10–20 Hz LiDAR stream
+    ``SortedGrid`` and the host planner's builders (``host_meta``) re-sort
+    the key set per scene — fine for i.i.d. uploads, wasteful for a 10–20 Hz LiDAR stream
     where frame t+1 keeps most of frame t's voxels. This numpy structure
     keeps only the *active* keys sorted (paired with their row ids) and
     supports the three stream mutations without a full re-sort:
@@ -147,9 +147,9 @@ class UpdatableSortedGrid:
     * ``insert(keys, rows)`` — batched insertion of sorted new keys at
       their ``searchsorted`` positions (O(n + m log n) merge, no re-sort).
 
-    ``lookup`` returns bit-identical results to ``SortedGridNp.lookup`` on
+    ``lookup`` returns bit-identical results to ``SortedGrid.lookup`` on
     the same active set: active keys are unique, and the sentinel rows the
-    capacity-shaped variant carries can never match a valid query, so
+    capacity-shaped grid carries can never match a valid query, so
     dropping them changes nothing.
     """
 
@@ -211,7 +211,7 @@ class UpdatableSortedGrid:
 
     def lookup(self, query_coords: np.ndarray,
                query_valid: np.ndarray) -> np.ndarray:
-        """Row ids for query coords; -1 if absent (``SortedGridNp`` twin)."""
+        """Row ids for query coords; -1 if absent (``SortedGrid`` twin)."""
         from repro.core.host_meta import linear_key_np
 
         r = self.resolution

@@ -4,8 +4,9 @@ The jitted builders in ``core.hashgrid`` / ``core.coir`` run *on the
 device* — on CPU they share the XLA stream and thread pool with model
 execution, so an async serving pipeline that builds plans in host threads
 would queue its metadata computations behind the waves it is trying to
-overlap with. These mirrors reproduce the same sorted-key binary-search
-flow op-for-op in numpy, keeping the whole offline pass (AdMAC + SOAR +
+overlap with. These mirrors give the same tables in numpy from a sorted-key
+binary search over the active rows alone, so their cost follows the active
+voxels and not the capacity, keeping the whole offline pass (AdMAC + SOAR +
 SPADE + tiles) on the host until ``engine.plan.upload_scene_plan`` moves
 the finished plan to the device.
 
@@ -20,6 +21,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro.analysis import spans
 from repro.core.coir import COIR
 from repro.core.hashgrid import UpdatableSortedGrid, kernel_offsets
 from repro.sparse.tensor import MAX_RESOLUTION, PAD_COORD
@@ -42,46 +44,62 @@ def linear_key_np(coords: np.ndarray, resolution: int,
     return key.astype(np.int32)
 
 
-class SortedGridNp:
-    """Numpy twin of ``hashgrid.SortedGrid`` (sorted keys + binary search)."""
+_OFFS2 = kernel_offsets(2, centered=False)   # [0,2)^3 down/up pair stencil
+_K2 = _OFFS2.shape[0]                        # 8
+
+
+class _ActiveGrid:
+    """Sorted linear keys of a layout's active rows (the AdMAC search side).
+
+    Only the rows with ``mask`` set are keyed and sorted, stably, so ties
+    between duplicate coordinates go to the lowest row. ``exact`` says the
+    keys are unique and every active coordinate lies inside ``[0,
+    resolution)^3``: then a key names one voxel and a hit is symmetric."""
 
     def __init__(self, coords: np.ndarray, mask: np.ndarray, resolution: int):
         self.resolution = resolution
-        keys = linear_key_np(coords, resolution, mask)
+        rows = np.flatnonzero(np.asarray(mask)).astype(np.int32)
+        coords = np.asarray(coords, np.int32)[rows]
+        keys = _raw_key(coords, resolution)
         order = np.argsort(keys, kind="stable")
-        self.sorted_keys = keys[order]
-        self.sorted_idx = order.astype(np.int32)
+        self.keys = keys[order]
+        self.rows = rows[order]
+        self.coords = coords[order]
+        n = len(rows)
+        #: rows already in key order from row 0: positions are row ids
+        self.prefix = bool(n == 0 or (self.rows[-1] == n - 1
+                                      and np.all(order[1:] > order[:-1])))
+        self.unique = bool(np.all(self.keys[1:] != self.keys[:-1]))
+        self.exact = self.unique and bool(
+            np.all((coords >= 0) & (coords < resolution)))
 
-    def lookup(self, query_coords: np.ndarray,
-               query_valid: np.ndarray) -> np.ndarray:
-        r = self.resolution
-        q = np.asarray(query_coords)
-        in_bounds = np.all((q >= 0) & (q < r), axis=-1)
-        valid = np.asarray(query_valid) & in_bounds
-        qkey = linear_key_np(q, r, valid)
-        pos = np.searchsorted(self.sorted_keys, qkey)
-        pos = np.clip(pos, 0, self.sorted_keys.shape[0] - 1)
-        found = valid & (self.sorted_keys[pos] == qkey)
-        return np.where(found, self.sorted_idx[pos], -1).astype(np.int32)
+    def search(self, qkeys: np.ndarray, after=None):
+        """(position, hit) of each query key; ``qkeys`` in ascending order
+        keep the binary searches in cache. ``after``, the (position, hit)
+        of ``qkeys - 1`` on a grid of ``unique`` keys, makes each search
+        one step: the next key sits one position on where that one hit."""
+        if after is None:
+            pos = np.searchsorted(self.keys, qkeys)
+        else:
+            pos = after[0] + after[1]
+        np.minimum(pos, len(self.keys) - 1, out=pos)
+        return pos, self.keys[pos] == qkeys
+
+    def row_of(self, pos: np.ndarray) -> np.ndarray:
+        """Row ids of grid positions, -1 kept."""
+        if self.prefix:
+            return pos
+        return np.append(self.rows, np.int32(-1))[pos]
 
 
-def query_neighbors_np(
-    out_coords: np.ndarray,
-    out_mask: np.ndarray,
-    in_coords: np.ndarray,
-    in_mask: np.ndarray,
-    offsets: np.ndarray,
-    resolution: int,
-    stride: int = 1,
-) -> np.ndarray:
-    """Numpy twin of ``hashgrid.query_neighbors``."""
-    grid = SortedGridNp(in_coords, in_mask, resolution)
-    out_coords = np.asarray(out_coords)
-    offsets = np.asarray(offsets)
-    probe = out_coords[:, None, :] * stride + offsets[None, :, :]
-    valid = np.broadcast_to(np.asarray(out_mask)[:, None],
-                            (out_coords.shape[0], offsets.shape[0]))
-    return grid.lookup(probe, valid)
+def _raw_key(coords: np.ndarray, resolution: int) -> np.ndarray:
+    """``linear_key_np`` with no sentinel: the int32 key of every row."""
+    if resolution > MAX_RESOLUTION:
+        raise ValueError(
+            f"resolution {resolution} > int32-safe max {MAX_RESOLUTION}")
+    r = np.int32(resolution)
+    c = np.asarray(coords, np.int32)
+    return (c[..., 0] * r + c[..., 1]) * r + c[..., 2]
 
 
 def _pack_bitmask_np(indices: np.ndarray) -> np.ndarray:
@@ -91,11 +109,42 @@ def _pack_bitmask_np(indices: np.ndarray) -> np.ndarray:
     v, k = indices.shape
     words = -(-k // 32)
     valid = np.zeros((v, 32 * words), bool)
-    valid[:, :k] = indices >= 0
-    bits = (valid.reshape(v, words, 32).astype(np.uint32)
-            << np.arange(32, dtype=np.uint32))
-    packed = bits.sum(axis=2, dtype=np.uint32)
+    np.greater_equal(indices, 0, out=valid[:, :k])
+    packed = np.packbits(valid, axis=1, bitorder="little").view("<u4")
+    packed = packed.astype(np.uint32, copy=False)
     return packed[:, 0] if words == 1 else packed
+
+
+def _row_index(rows: np.ndarray):
+    """``rows`` as an index: a slice where they are 0..n-1 in order."""
+    n = len(rows)
+    if n and rows[-1] == n - 1 and np.all(rows[1:] > rows[:-1]):
+        return slice(0, n)
+    return rows
+
+
+def _coir(table: np.ndarray, rows: np.ndarray, mask: np.ndarray) -> COIR:
+    """COIR of a capacity-shaped table that is all -1 outside the active
+    ``rows``: only their bitmask words are packed, the rest stay 0."""
+    cap, k = table.shape
+    words = -(-k // 32)
+    bitmask = np.zeros((cap,) if words == 1 else (cap, words), np.uint32)
+    if len(rows):
+        at = _row_index(rows)
+        bitmask[at] = _pack_bitmask_np(table[at])
+    return COIR(table, bitmask, np.asarray(mask))
+
+
+def _scatter_coir(rows: np.ndarray, idx: np.ndarray, capacity: int,
+                  mask: np.ndarray) -> COIR:
+    """The capacity-shaped COIR whose active ``rows`` hold ``idx``."""
+    table = np.full((capacity, idx.shape[1]), -1, np.int32)
+    table[_row_index(rows)] = idx
+    return _coir(table, rows, mask)
+
+
+def _pair_stencil(offsets: np.ndarray, stride: int) -> bool:
+    return stride == 2 and np.array_equal(offsets, _OFFS2)
 
 
 def build_cirf_np(
@@ -107,10 +156,70 @@ def build_cirf_np(
     resolution: int,
     stride: int = 1,
 ) -> COIR:
-    """Numpy twin of ``coir.build_cirf`` (COIR with numpy leaves)."""
-    idx = query_neighbors_np(out_coords, out_mask, in_coords, in_mask,
-                             offsets, resolution, stride)
-    return COIR(idx, _pack_bitmask_np(idx), np.asarray(out_mask))
+    """Numpy twin of ``coir.build_cirf`` (COIR with numpy leaves): row i,
+    plane k holds the lowest input row at ``out_coords[i] * stride +
+    offsets[k]`` inside ``[0, resolution)^3``, else -1.
+
+    Only active rows are searched, plane by plane, over the input's sorted
+    active keys: a probe's key is the output row's key plus the offset's key
+    delta, masked where an axis leaves the grid, so one plane's queries
+    arrive in key order. Where the hits are symmetric (the same arrays in
+    and out, stride 1, ``offsets[K-1-k] == -offsets[k]``, and an ``exact``
+    grid) plane ``K-1-k`` is filled from plane k by scatter and only the
+    centre and half the planes are searched. The ``[0,2)^3`` stride-2 table
+    comes from one parent search per fine voxel (``down_up_coirs_np``).
+    Adds ``rows`` and ``probes`` to the enclosing span's counters."""
+    offsets = np.asarray(offsets)
+    if _pair_stencil(offsets, stride):
+        pair = _pair_tables(out_coords, out_mask, in_coords, in_mask,
+                            resolution)
+        if pair is not None:
+            return pair[0]
+    return _search_cirf(out_coords, out_mask, in_coords, in_mask, offsets,
+                        resolution, stride)
+
+
+def _search_cirf(out_coords, out_mask, in_coords, in_mask, offsets,
+                 resolution: int, stride: int) -> COIR:
+    grid = _ActiveGrid(in_coords, in_mask, resolution)
+    same = stride == 1 and out_coords is in_coords and out_mask is in_mask
+    if same:
+        rows, base, bkeys = grid.rows, grid.coords, grid.keys
+    else:
+        rows = np.flatnonzero(np.asarray(out_mask)).astype(np.int32)
+        base = np.asarray(out_coords, np.int32)[rows] * np.int32(stride)
+        bkeys = _raw_key(base, resolution)
+        order = np.argsort(bkeys, kind="stable")
+        rows, base, bkeys = rows[order], base[order], bkeys[order]
+    n, k_vol = len(rows), len(offsets)
+    cap = np.asarray(out_mask).shape[0]
+    pos_t = np.full((k_vol, n), -1, np.int32)   # plane-major grid positions
+    recip = (same and grid.exact
+             and np.array_equal(offsets[::-1], -offsets))
+    planes = (k_vol + 1) // 2 if recip else k_vol
+    if n and len(grid.keys):
+        r = resolution
+        # per axis and offset value: does the probe stay on the grid?
+        inside = [{int(v): (base[:, d] + v >= 0) & (base[:, d] + v < r)
+                   for v in np.unique(offsets[:, d])} for d in range(3)]
+        deltas = (offsets[:, 0] * r + offsets[:, 1]) * r + offsets[:, 2]
+        found = None
+        for k in range(planes):
+            o = offsets[k]
+            # the next z along a column: step on from the last plane's search
+            step = grid.unique and k and deltas[k] == deltas[k - 1] + 1
+            found = grid.search(bkeys + np.int32(deltas[k]),
+                                found if step else None)
+            pos, hit = found[0], found[1].copy()
+            hit &= inside[0][int(o[0])]
+            hit &= inside[1][int(o[1])]
+            hit &= inside[2][int(o[2])]
+            pos_t[k] = np.where(hit, pos, np.int32(-1))
+            if recip and k != k_vol - 1 - k:
+                a = np.flatnonzero(hit).astype(np.int32)
+                pos_t[k_vol - 1 - k, pos[a]] = a
+        spans.count(rows=n, probes=n * planes)
+    return _scatter_coir(rows, grid.row_of(pos_t.T), cap, out_mask)
 
 
 def build_corf_np(
@@ -122,17 +231,90 @@ def build_corf_np(
     resolution: int,
     stride: int = 1,
 ) -> COIR:
-    """Numpy twin of ``coir.build_corf``."""
-    out_res = max(resolution // stride, 1) if stride > 1 else resolution
-    grid = SortedGridNp(out_coords, out_mask, out_res)
-    in_coords = np.asarray(in_coords)
+    """Numpy twin of ``coir.build_corf``: input row j, plane k holds the
+    lowest output row at ``(in_coords[j] - offsets[k]) / stride`` where that
+    divides exactly and lies on the output grid, else -1. Only active input
+    rows are searched, over the output's sorted active keys; the ``[0,2)^3``
+    stride-2 table comes from one parent search per fine voxel. Adds
+    ``rows`` and ``probes`` to the enclosing span's counters."""
     offsets = np.asarray(offsets)
-    diff = in_coords[:, None, :] - offsets[None, :, :]
-    exact = np.all(diff % stride == 0, axis=-1)
-    probe = diff // stride
-    valid = np.asarray(in_mask)[:, None] & exact
-    idx = grid.lookup(probe, valid)
-    return COIR(idx, _pack_bitmask_np(idx), np.asarray(in_mask))
+    if _pair_stencil(offsets, stride):
+        pair = _pair_tables(out_coords, out_mask, in_coords, in_mask,
+                            resolution)
+        if pair is not None:
+            return pair[1]
+    return _search_corf(out_coords, out_mask, in_coords, in_mask, offsets,
+                        resolution, stride)
+
+
+def _search_corf(out_coords, out_mask, in_coords, in_mask, offsets,
+                 resolution: int, stride: int) -> COIR:
+    out_res = max(resolution // stride, 1) if stride > 1 else resolution
+    grid = _ActiveGrid(out_coords, out_mask, out_res)
+    rows = np.flatnonzero(np.asarray(in_mask)).astype(np.int32)
+    coords = np.asarray(in_coords, np.int32)[rows]
+    n, k_vol = len(rows), len(offsets)
+    idx = np.full((n, k_vol), -1, np.int32)
+    if n and len(grid.keys):
+        for k in range(k_vol):
+            diff = coords - offsets[k]
+            probe = diff // stride
+            valid = np.all((diff % stride == 0) & (probe >= 0)
+                           & (probe < out_res), axis=-1)
+            pos, hit = grid.search(_raw_key(probe, out_res))
+            idx[:, k] = np.where(hit & valid, grid.row_of(pos), -1)
+        spans.count(rows=n, probes=n * k_vol)
+    return _scatter_coir(rows, idx, np.asarray(in_mask).shape[0], in_mask)
+
+
+def _pair_tables(coarse_coords, coarse_mask, fine_coords, fine_mask,
+                 resolution: int) -> tuple[COIR, COIR] | None:
+    """The ``[0,2)^3`` stride-2 down and up tables from one search of the
+    coarse level's sorted active keys, or None where it would not give the
+    general search's tables (an odd fine ``resolution``, or a grid that is
+    not ``exact``). Each active fine voxel has one parent, ``c // 2``, at plane
+    ``(c0 % 2) * 4 + (c1 % 2) * 2 + c2 % 2``: ``D[parent, k] = fine row``
+    and ``U[fine row, k] = parent``."""
+    if resolution % 2:
+        return None
+    fine = _ActiveGrid(fine_coords, fine_mask, resolution)
+    coarse = _ActiveGrid(coarse_coords, coarse_mask, resolution // 2)
+    if not (fine.exact and coarse.exact):
+        return None
+    fc = fine.coords
+    k_par = (fc[:, 0] % 2) * 4 + (fc[:, 1] % 2) * 2 + fc[:, 2] % 2
+    pos = np.zeros(len(fc), np.int64)
+    hit = np.zeros(len(fc), bool)
+    if len(fc) and len(coarse.keys):
+        pos, hit = coarse.search(_raw_key(fc // 2, resolution // 2))
+        spans.count(rows=len(fc), probes=len(fc))
+    f, p, k = fine.rows[hit], coarse.rows[pos[hit]], k_par[hit]
+    down = np.full((np.asarray(coarse_mask).shape[0], _K2), -1, np.int32)
+    down[p, k] = f
+    up = np.full((np.asarray(fine_mask).shape[0], _K2), -1, np.int32)
+    up[f, k] = p
+    return (_coir(down, coarse.rows, coarse_mask),
+            _coir(up, fine.rows, fine_mask))
+
+
+def down_up_coirs_np(
+    coarse_coords: np.ndarray,
+    coarse_mask: np.ndarray,
+    fine_coords: np.ndarray,
+    fine_mask: np.ndarray,
+    fine_resolution: int,
+) -> tuple[COIR, COIR]:
+    """A pyramid level pair's ``[0,2)^3`` stride-2 tables: ``build_cirf_np``
+    of the down conv and ``transposed_coir_np`` of the up conv, from one
+    parent search per fine voxel where the grids allow it."""
+    pair = _pair_tables(coarse_coords, coarse_mask, fine_coords, fine_mask,
+                        fine_resolution)
+    if pair is not None:
+        return pair
+    return (_search_cirf(coarse_coords, coarse_mask, fine_coords, fine_mask,
+                         _OFFS2, fine_resolution, 2),
+            _search_corf(coarse_coords, coarse_mask, fine_coords, fine_mask,
+                         _OFFS2, fine_resolution, 2))
 
 
 def transposed_coir_np(
@@ -256,10 +438,6 @@ def downsample_coords_np(
 # Streaming: delta-based incremental metadata for overlapping LiDAR frames
 # ---------------------------------------------------------------------------
 
-_OFFS2 = kernel_offsets(2, centered=False)   # [0,2)^3 down/up pair stencil
-_K2 = _OFFS2.shape[0]                        # 8
-
-
 def _key_offset(shift: np.ndarray, resolution: int) -> int:
     """Linear-key delta of a uniform coordinate shift (valid while every
     shifted coordinate stays inside ``[0, resolution)^3``)."""
@@ -280,10 +458,9 @@ def _prefix_lookup(keys_sorted: np.ndarray, probe_coords: np.ndarray,
                    resolution: int) -> np.ndarray:
     """Neighbour lookup against a sorted-prefix active set (row == rank).
 
-    Bit-identical to ``SortedGridNp.lookup`` when the voxel list is laid out
-    as its own sorted-key prefix (the ``downsample_coords_np`` canonical
-    order): the capacity-shaped grid's sentinel rows sort after every valid
-    key and can never match a valid query, so the prefix alone suffices.
+    Bit-identical to ``build_cirf_np``'s search when the voxel list is laid
+    out as its own sorted-key prefix (the ``downsample_coords_np`` canonical
+    order): a row's position in the sorted keys is its row id.
     """
     q = np.asarray(probe_coords)
     in_bounds = np.all((q >= 0) & (q < resolution), axis=-1)
@@ -472,10 +649,9 @@ class StreamMetaState:
         for li in range(self.n_levels - 1):
             fc, fm, fres = geo[li]
             cc, cm, _ = geo[li + 1]
-            self.down.append(
-                build_cirf_np(cc, cm, fc, fm, _OFFS2, fres, stride=2))
-            self.up.append(
-                transposed_coir_np(cc, cm, fc, fm, fres, 2, 2))
+            down, up = down_up_coirs_np(cc, cm, fc, fm, fres)
+            self.down.append(down)
+            self.up.append(up)
 
     # -- one stream step ---------------------------------------------------
 

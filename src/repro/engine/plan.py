@@ -39,8 +39,8 @@ from repro.core.hashgrid import kernel_offsets
 from repro.core.host_meta import (
     StreamMetaState,
     build_cirf_np,
+    down_up_coirs_np,
     downsample_coords_np,
-    transposed_coir_np,
 )
 from repro.core.soar import raster_order, soar_order
 from repro.analysis.runtime import ordered_condition, ordered_lock
@@ -796,7 +796,6 @@ def _build_scene_plan(
             f"{'a' if spec.stem is not None else 'no'} stem site but cfg "
             f"has {len(cfg.widths)} and {'a' if stem else 'no'} stem site "
             "— was it built from another config?")
-    offs2 = kernel_offsets(2, centered=False)
     offs3 = kernel_offsets(3)
     geometry = level_geometry(t, cfg)
     site_kw = dict(plan_tiles=plan_tiles, mem_budget=mem_budget, order=order,
@@ -812,10 +811,8 @@ def _build_scene_plan(
                 sub_coir = build_cirf_np(coords, mask, coords, mask, offs3, res)
                 if li < len(cfg.widths) - 1:
                     dn_coords, dn_mask, _ = geometry[li + 1]
-                    down_coir = build_cirf_np(
-                        dn_coords, dn_mask, coords, mask, offs2, res, stride=2)
-                    up_coir = transposed_coir_np(dn_coords, dn_mask, coords,
-                                                 mask, res, 2, 2)
+                    down_coir, up_coir = down_up_coirs_np(
+                        dn_coords, dn_mask, coords, mask, res)
                     # resolution-changing convs stay on the coarse single dispatch
                     down = ConvPlan(down_coir)
                     up = ConvPlan(up_coir)

@@ -49,8 +49,8 @@ from jax.sharding import PartitionSpec as P
 from repro.core.hashgrid import kernel_offsets
 from repro.core.host_meta import (
     build_cirf_np,
+    down_up_coirs_np,
     shard_halo_tables_np,
-    transposed_coir_np,
 )
 from repro.dist.collectives import halo_exchange_local
 from repro.dist.compat import shard_map
@@ -174,7 +174,6 @@ def build_sharded_scene_plan_host(
     vs = layout.shard_size(t.capacity)
     chunk = math.gcd(max(int(layout.bn_chunk), 1), vs)
     layout = replace(layout, bn_chunk=chunk)
-    offs2 = kernel_offsets(2, centered=False)
     offs3 = kernel_offsets(3)
     geometry = level_geometry(t, cfg)
     levels: list[ShardedLevelPlan] = []
@@ -188,10 +187,8 @@ def build_sharded_scene_plan_host(
         halo_budget = {"sub": int(sub.send_rows.shape[-1])}
         if li < len(cfg.widths) - 1:
             dn_coords, dn_mask, _ = geometry[li + 1]
-            down_coir = build_cirf_np(
-                dn_coords, dn_mask, coords, mask, offs2, res, stride=2)
-            up_coir = transposed_coir_np(dn_coords, dn_mask, coords, mask,
-                                         res, 2, 2)
+            down_coir, up_coir = down_up_coirs_np(dn_coords, dn_mask,
+                                                  coords, mask, res)
             down, halo_rows["down"] = _shard_conv(
                 down_coir.indices, dn_mask, layout.n_shards, layout.halo)
             up, halo_rows["up"] = _shard_conv(
