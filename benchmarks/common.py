@@ -1,16 +1,12 @@
 """Shared benchmark scaffolding: scene building + timing + CSV rows.
 
-Timing delegates to :func:`repro.engine.autotune.measure` — the same
-warmup + median-of-k harness the profile-guided dispatcher uses — so
-benchmark numbers and autotune cost-table entries are directly
-comparable. The fused-kernel ``block_n`` sweep lives in
-``repro.engine.autotune`` now; a deprecation shim below keeps old
-imports working."""
+Timing is warmup + median-of-k wall clock (:func:`measure`)."""
 from __future__ import annotations
 
 import time
-import warnings
+from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -18,7 +14,6 @@ from repro.core import soar
 from repro.core.hashgrid import build_neighbor_table, kernel_offsets
 from repro.core.sparse_conv import submanifold_coir
 from repro.data.scenes import make_scene
-from repro.engine.autotune import measure
 from repro.sparse.tensor import SparseVoxelTensor
 
 ROWS: list[tuple[str, float, str]] = []
@@ -29,11 +24,40 @@ def emit(name: str, us_per_call: float, derived: str):
     print(f"{name},{us_per_call:.1f},{derived}")
 
 
+@dataclass(frozen=True)
+class Measurement:
+    """One timed function: median and IQR spread of ``k`` samples (us)."""
+
+    median_us: float
+    spread_us: float
+    k: int
+    times_us: tuple = ()
+
+
+def measure(fn, *args, warmup: int = 1, k: int = 5) -> Measurement:
+    """Warmup + median-of-``k`` wall-clock of ``fn(*args)`` in us.
+
+    Every call — warmup included — is ``jax.block_until_ready``'d, so
+    async dispatch can't leak device time out of (or host time into) the
+    sample. The median defeats one-off scheduler hiccups; ``spread_us``
+    (interquartile range) is the noise floor callers can gate on.
+    """
+    for _ in range(max(int(warmup), 0)):
+        jax.block_until_ready(fn(*args))
+    times = []
+    for _ in range(max(int(k), 1)):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        times.append((time.perf_counter() - t0) * 1e6)
+    times.sort()
+    spread = float(np.percentile(times, 75) - np.percentile(times, 25))
+    return Measurement(float(np.median(times)), spread, len(times),
+                       tuple(times))
+
+
 def time_fn(fn, *args, iters=3, warmup=1, reps=1):
     """Median us/call over ``iters * reps`` timed calls (median is robust
-    to background load on shared CI hosts). Thin wrapper over
-    ``engine.autotune.measure`` so benches and the autotuner share one
-    timing harness."""
+    to background load on shared CI hosts)."""
     k = max(int(iters) * int(reps), 1)
     return measure(fn, *args, warmup=warmup, k=k).median_us
 
@@ -56,16 +80,11 @@ def scene_metadata(t: SparseVoxelTensor, resolution: int):
 # -- standalone bench CLIs ---------------------------------------------------
 
 def standalone_bench_main(run, module_name: str, quick_help: str,
-                          description: str | None = None, argv=None,
-                          configure=None, run_kw=None) -> None:
+                          description: str | None = None,
+                          argv=None) -> None:
     """Shared ``main()`` for benches with their own CI smoke CLI
     (``--quick`` / ``--json``): one place owns the CSV header, timing and
-    the ``bench-rows/v1`` JSON artifact schema.
-
-    ``configure(parser)`` lets a bench register extra CLI flags;
-    ``run_kw(args) -> dict`` maps the parsed namespace to extra keyword
-    arguments for ``run`` (e.g. ``--seed-from`` in ``bench_dispatch``).
-    """
+    the ``bench-rows/v1`` JSON artifact schema."""
     import argparse
     import json
     import sys
@@ -74,13 +93,10 @@ def standalone_bench_main(run, module_name: str, quick_help: str,
     ap.add_argument("--quick", action="store_true", help=quick_help)
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write rows as a JSON artifact (CI perf log)")
-    if configure is not None:
-        configure(ap)
     args = ap.parse_args(argv)
-    extra = run_kw(args) if run_kw is not None else {}
     print("name,us_per_call,derived")
     t0 = time.time()
-    run(quick=args.quick, **extra)
+    run(quick=args.quick)
     total_s = time.time() - t0
     print(f"# total {total_s:.1f}s", file=sys.stderr)
     if args.json:
@@ -97,14 +113,3 @@ def standalone_bench_main(run, module_name: str, quick_help: str,
         print(f"# wrote {len(payload['rows'])} rows to {args.json}",
               file=sys.stderr)
 
-
-# -- fused-kernel block_n autotune (moved) -----------------------------------
-
-def autotune_block_n(*args, **kw):
-    """Deprecated shim: the ``block_n`` sweep moved into the engine."""
-    warnings.warn(
-        "benchmarks.common.autotune_block_n is deprecated; use "
-        "repro.engine.autotune.autotune_block_n",
-        DeprecationWarning, stacklevel=2)
-    from repro.engine.autotune import autotune_block_n as impl
-    return impl(*args, **kw)

@@ -18,11 +18,10 @@ import time
 
 # modules cheap enough for the CI smoke job (reduced configs, small scenes).
 # bench_serving, bench_admission, bench_sspnna, bench_sharded_scene,
-# bench_streaming, bench_dispatch and bench_faults are smoked separately
-# (their own --quick CLIs write BENCH_serving.json / BENCH_admission.json /
-# BENCH_sspnna.json / BENCH_sharded_scene.json / BENCH_streaming.json /
-# BENCH_dispatch.json / BENCH_faults.json — the last in the chaos job) so
-# they aren't duplicated here.
+# bench_streaming and bench_faults are smoked separately (their own --quick
+# CLIs write BENCH_serving.json / BENCH_admission.json / BENCH_sspnna.json /
+# BENCH_sharded_scene.json / BENCH_streaming.json / BENCH_faults.json — the
+# last in the chaos job) so they aren't duplicated here.
 QUICK = ("bench_soar", "bench_spade_attrs", "bench_moe", "bench_dataflow")
 
 
@@ -43,7 +42,6 @@ def main(argv=None) -> None:
         bench_admission,
         bench_coir,
         bench_dataflow,
-        bench_dispatch,
         bench_faults,
         bench_lm,
         bench_moe,
@@ -56,10 +54,10 @@ def main(argv=None) -> None:
         bench_streaming,
     )
 
-    modules = [bench_dispatch, bench_coir, bench_soar, bench_spade_attrs,
-               bench_dataflow, bench_sspnna, bench_scn, bench_serving,
-               bench_admission, bench_sharded_scene, bench_streaming,
-               bench_faults, bench_moe, bench_lm]
+    modules = [bench_coir, bench_soar, bench_spade_attrs, bench_dataflow,
+               bench_sspnna, bench_scn, bench_serving, bench_admission,
+               bench_sharded_scene, bench_streaming, bench_faults, bench_moe,
+               bench_lm]
     if args.only:
         wanted = {m.strip() for m in args.only.split(",")}
         known = {m.__name__.split(".")[-1] for m in modules}

@@ -70,7 +70,6 @@ def run_plans(root: Path) -> list[Finding]:
         check_stream_state,
     )
     from repro.data.scenes import N_CLASSES
-    from repro.engine.autotune import CostTable
     from repro.engine.backends import BreakerBoard, default_registry
     from repro.engine.plan import PlanCache, StreamPlanState
     from repro.engine.shard import ShardLayout
@@ -102,11 +101,10 @@ def run_plans(root: Path) -> list[Finding]:
     state.plan_frame(t, 1, ego_shift=(1, 0, 0))
     out.extend(check_stream_state(state, "stream"))
 
-    # cache keys must rotate with version/topology/generations
+    # cache keys must rotate with version/topology/breaker generation
     cache = PlanCache(capacity=cap)
     out.extend(check_cache_keys(
-        cache, t, cfg, autotune=CostTable(),
-        breakers=BreakerBoard(default_registry())))
+        cache, t, cfg, breakers=BreakerBoard(default_registry())))
     return out
 
 

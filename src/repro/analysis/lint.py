@@ -39,7 +39,6 @@ DEPRECATED_SHIMS: dict[str, frozenset[str]] = {
     "repro.core.sparse_conv": frozenset({"sparse_conv_cirf"}),
     "repro.kernels.sspnna.ops": frozenset(
         {"sspnna_conv", "sspnna_conv_from_plan"}),
-    "benchmarks.common": frozenset({"autotune_block_n"}),
 }
 
 _HOT_FUNCS = ("_dispatch_stage",)

@@ -20,8 +20,8 @@ end to end:
 * ``REPRO-P005`` — sharded halo tables broken: send rows outside the
   sender's shard, references to halo slots nobody sends, self-halo.
 * ``REPRO-P006`` — cache keys don't rotate: a ``PlanCache`` key that fails
-  to mix ``_PLAN_VERSION``, topology, or the autotune/breaker generations
-  serves stale plans after a flip.
+  to mix ``_PLAN_VERSION``, topology, or the breaker generation serves
+  stale plans after a breaker trip.
 
 ``check_plan`` dispatches on plan type; every check returns
 ``list[Finding]`` (empty = clean) and never raises on malformed input.
@@ -311,11 +311,11 @@ def check_stream_state(state, path: str = "stream") -> list[Finding]:
     return out
 
 
-def check_cache_keys(cache, t, cfg, *, autotune=None, breakers=None,
+def check_cache_keys(cache, t, cfg, *, breakers=None,
                      path: str = "plan_cache") -> list[Finding]:
     """Verify ``PlanCache`` keys rotate with everything that must rotate
     them: the table-layout ``_PLAN_VERSION``, the mesh topology, and the
-    autotune/breaker generations (mixed in via their ``repr``)."""
+    breaker generation (mixed in via the board's ``repr``)."""
     import repro.engine.plan as plan_mod
     out: list[Finding] = []
     base = cache.key_for(t, cfg)
@@ -332,26 +332,26 @@ def check_cache_keys(cache, t, cfg, *, autotune=None, breakers=None,
             cache.key_for(t, cfg, topology="b"):
         out.append(Finding("REPRO-P006", path,
                            "key does not mix the mesh topology"))
-    for label, obj in (("autotune", autotune), ("breakers", breakers)):
-        if obj is None:
-            continue
-        k0 = cache.key_for(t, cfg, **{label: obj})
-        if not hasattr(obj, "generation"):
-            out.append(Finding(
-                "REPRO-P006", path,
-                f"{label} object {type(obj).__name__} has no generation "
-                f"counter to mix into keys"))
-            continue
-        obj.generation += 1
-        try:
-            k1 = cache.key_for(t, cfg, **{label: obj})
-        finally:
-            obj.generation -= 1
-        if k0 == k1:
-            out.append(Finding(
-                "REPRO-P006", path,
-                f"key does not rotate with the {label} generation "
-                f"({type(obj).__name__!s}.__repr__ must include it)"))
+    if breakers is None:
+        return out
+    name = type(breakers).__name__
+    if not hasattr(breakers, "generation"):
+        out.append(Finding(
+            "REPRO-P006", path,
+            f"breakers object {name} has no generation counter to mix "
+            f"into keys"))
+        return out
+    k0 = cache.key_for(t, cfg, breakers=breakers)
+    breakers.generation += 1
+    try:
+        k1 = cache.key_for(t, cfg, breakers=breakers)
+    finally:
+        breakers.generation -= 1
+    if k0 == k1:
+        out.append(Finding(
+            "REPRO-P006", path,
+            f"key does not rotate with the breakers generation "
+            f"({name}.__repr__ must include it)"))
     return out
 
 

@@ -14,10 +14,10 @@ at the acquisition site.
 The environment variable is read at lock-creation time, so module-level
 locks (``serving.api._SERVE_LOCK``, ``serving.faults._ACTIVE_LOCK``) are
 only checked when the variable is set before the first ``repro`` import;
-per-instance locks (plan cache, scheduler pool, breakers, autotune,
-streams) are checked for any object created while it is set. This module
-must stay dependency-free (``os``/``threading`` only): every lock-owning
-module in ``src/repro`` imports it.
+per-instance locks (plan cache, scheduler pool, breakers, streams) are
+checked for any object created while it is set. This module must stay
+dependency-free (``os``/``threading`` only): every lock-owning module in
+``src/repro`` imports it.
 """
 from __future__ import annotations
 
@@ -35,7 +35,6 @@ LOCK_ORDER = (
     "plan_cache",        # PlanCache._lock (entry map + coalescing table)
     "plan_cache.dev",    # per-entry device-upload memo lock
     "breakers",          # BreakerBoard._lock (per-backend breaker state)
-    "autotune",          # CostTable._lock (measured-cost table)
     "faults.injector",   # FaultInjector._lock (seeded trial counters)
     "faults.install",    # serving.faults._ACTIVE_LOCK (ambient injector)
 )

@@ -8,20 +8,6 @@ that owns the mesh, registry view and plan cache; mesh-sharded scenes
 (``engine.shard``) execute as the registered ``"sharded"`` backend with
 halo exchange for cross-shard receptive fields.
 """
-from repro.engine.autotune import (
-    CostTable,
-    Measurement,
-    ShapeSig,
-    autotune_block_n,
-    default_cache_path,
-    device_fingerprint,
-    measure,
-    measure_backends,
-    profile_group,
-    reprofile,
-    seed_cost_table,
-    signature,
-)
 from repro.engine.api import (
     apply_unet,
     available_backends,
@@ -96,15 +82,12 @@ __all__ = [
     "Backend",
     "BackendRegistry",
     "ConvPlan",
-    "CostTable",
-    "Measurement",
     "Dispatch",
     "ExecutionContext",
     "LevelPlan",
     "PlanCache",
     "PlanSpec",
     "ScenePlan",
-    "ShapeSig",
     "ShardLayout",
     "ShardedScenePlan",
     "SignatureFamily",
@@ -112,7 +95,6 @@ __all__ = [
     "TileArrays",
     "apply_unet",
     "apply_unet_sharded",
-    "autotune_block_n",
     "available_backends",
     "build_plan_spec",
     "build_scene_plan",
@@ -124,24 +106,16 @@ __all__ = [
     "conv_block",
     "conv_plan_for_layer",
     "current_context",
-    "default_cache_path",
     "default_context",
     "default_registry",
-    "device_fingerprint",
     "dispatch_from_dataflow",
     "level_geometry",
-    "measure",
-    "measure_backends",
     "pin_halo",
-    "profile_group",
     "reference_plan",
     "register_backend",
-    "reprofile",
     "resolve_backend",
     "scene_key",
-    "seed_cost_table",
     "set_default_context",
-    "signature",
     "sparse_conv",
     "upload_scene_plan",
     "upload_sharded_scene_plan",

@@ -53,29 +53,12 @@ class ExecutionContext:
     #: context (typed loosely so the engine layer doesn't import serving);
     #: None = FIFO admission
     admission: object | None = None
-    #: measured-dispatch cost table (``engine.autotune.CostTable``; typed
-    #: loosely so the dataclass stays import-light). When set, plan builds
-    #: under this context consult measured winners before the analytical
-    #: model, and a winner flip invalidates ``plan_cache`` (wired below).
-    autotune: object | None = None
-    #: idle-gap re-profiling budget per scheduler tick, in ms. 0 (the
-    #: default — notably in tests) disables online re-profiling entirely;
-    #: serving engines only install the ``WaveScheduler`` idle hook when
-    #: this is positive *and* ``autotune`` is set.
-    autotune_reprofile_ms: float = 0.0
 
     def __post_init__(self):
-        # plans cached under a measured decision must not outlive it: when
-        # the table's winner flips, every cached plan is dropped (keys also
-        # rotate — the table's generation is repr'd into them)
-        hook = getattr(self.autotune, "add_flip_hook", None)
-        if hook is not None:
-            hook(self.plan_cache.invalidate)
-        # same invariant for circuit breakers: a breaker trip reroutes new
-        # plan builds (engine.plan consults registry.breakers), so plans
-        # cached under the old routing must rotate out — the board's
-        # generation is repr'd into keys AND the cache is invalidated
-        # eagerly on every breaker state change
+        # a breaker trip reroutes new plan builds (engine.plan consults
+        # registry.breakers), so plans cached under the old routing must
+        # rotate out — the board's generation is repr'd into keys AND the
+        # cache is invalidated eagerly on every breaker state change
         board = getattr(self.registry, "breakers", None)
         if board is not None:
             board.add_hook(self.plan_cache.invalidate)

@@ -85,7 +85,7 @@ class Dispatch:
     delta_o: int = 0
     delta_i: int = 0
     n_tiles: int = 0
-    block_n: int = 0  # pinned kernel N-block (0 = full N); see autotune_block_n
+    block_n: int = 0  # kernel N-block (0 = full N)
 
 
 REFERENCE_DISPATCH = Dispatch()
@@ -265,8 +265,6 @@ def build_signature_family(
     ``build_plan_spec``). Buckets no representative scene fits keep
     ``spec=None`` (reference plans — still one signature per bucket).
     """
-    from dataclasses import replace
-
     from repro.sparse.tensor import compact_to_capacity
 
     sizes = [int(np.asarray(t.mask).sum()) for t in scenes]
@@ -334,19 +332,16 @@ class PlanCache:
         self._lock = ordered_lock("plan_cache")
         self.hits = 0
         self.misses = 0
-        self.invalidations = 0
 
     def invalidate(self) -> int:
         """Drop every cached entry (in-flight builds are unaffected: they
-        insert fresh entries when they land). This is the autotune
-        winner-flip hook — ``engine.autotune.CostTable`` fires it when a
-        measured winner changes, so plans keyed under the old decision are
+        insert fresh entries when they land). ``ExecutionContext`` hooks it
+        to its breaker board, so plans keyed under the old routing are
         rebuilt instead of served stale. Returns the number of entries
         dropped."""
         with self._lock:
             n = len(self._plans)
             self._plans.clear()
-            self.invalidations += 1
         return n
 
     @staticmethod
@@ -570,8 +565,6 @@ def build_plan_spec(
     order: str = "soar",
     soar_chunk: int = 512,
     tile_margin: float = 2.0,
-    tune_block_n=None,
-    autotune=None,
 ) -> PlanSpec:
     """Freeze per-level dispatch decisions from representative scenes.
 
@@ -586,25 +579,12 @@ def build_plan_spec(
     own, its rows in level 0's order. A site pinned to the fused kernel
     whose VMEM fails REPRO-H003 (``_fits_vmem``) is pinned to the
     reference backend instead, on SPADE's walk (``_kernel_refused``).
-
-    ``tune_block_n`` is an optional ``(c_in, n_out, delta_o, delta_i) -> int``
-    hook (e.g. ``repro.engine.autotune.autotune_block_n``) that picks the
-    fused kernel's N-block per layer signature; the choice is pinned in each
-    level's ``Dispatch.block_n`` so every plan built from this spec runs the
-    tuned block instead of defaulting to full-N.
-
-    ``autotune`` is an optional measured :class:`~repro.engine.autotune.
-    CostTable`: each level's analytical decision is overridden by the
-    cheapest *measured* backend at the level's shape signature when the
-    table has one, and left untouched (miss recorded) when it doesn't — a
-    cold table reproduces the analytical spec bitwise.
     """
     n_levels = len(cfg.widths)
     stem = stem_site(cfg)
     # (kernel size, C_in, C_out) of each site: the levels, then the stem
     sites = [(3, w, w) for w in cfg.widths] + ([stem] if stem else [])
     per_site: list[list[spade.SparsityAttributes]] = [[] for _ in sites]
-    site_density: list[float] = [0.0] * len(sites)
     geo_attrs = []
     for t in scenes:
         geometry = level_geometry(t, cfg)
@@ -613,17 +593,15 @@ def build_plan_spec(
             coir = build_cirf_np(coords, mask, coords, mask,
                                  kernel_offsets(3), res)
             rows.append((coir, _order_rows(coir, coords, mask, order,
-                                           soar_chunk), mask, res))
+                                           soar_chunk), mask))
         if stem is not None:
             coords, mask, res = geometry[0]
             rows.append((build_cirf_np(coords, mask, coords, mask,
                                        kernel_offsets(stem[0]), res),
-                         rows[0][1], mask, res))
-        for si, (coir, ordering, mask, res) in enumerate(rows):
+                         rows[0][1], mask))
+        for si, (coir, ordering, mask) in enumerate(rows):
             per_site[si].append(spade.extract_attributes(
                 np.asarray(coir.indices), np.asarray(mask), ordering))
-            site_density[si] += (float(np.asarray(mask).sum())
-                                 / float(max(res, 1)) ** 3 / len(scenes))
         geo_attrs.append(rows)
 
     dispatches = []
@@ -632,11 +610,6 @@ def build_plan_spec(
         msa = spade.meta_attributes(per_site[si])
         d, _ = _explore(f"level{si}" if si < n_levels else "stem", msa,
                         cfg.capacity, k, c_in, c_out, mem_budget)
-        if autotune is not None:
-            d = autotune.adjust_dispatch(
-                d, n_in=cfg.capacity, n_out=cfg.capacity,
-                c_in=c_in, c_out=c_out,
-                density=site_density[si], kernel_volume=k)
         if d.backend == SSPNNA:
             # worst observed budgeted tile count across the rep scenes
             observed = max(
@@ -645,10 +618,7 @@ def build_plan_spec(
                 for rows in geo_attrs)
             bound = max_tiles(cfg.capacity, d.delta_o, d.delta_i, k)
             n_tiles = min(bound, int(np.ceil(tile_margin * observed)) + 2)
-            block_n = (int(tune_block_n(c_in, c_out, d.delta_o, d.delta_i))
-                       if tune_block_n is not None else d.block_n)
-            d = Dispatch(d.backend, d.flavor, d.walk, d.delta_o, d.delta_i,
-                         n_tiles, block_n)
+            d = replace(d, n_tiles=n_tiles)
             if not _fits_vmem(d, c_in, c_out, k):
                 d = _kernel_refused(d)
         dispatches.append(d)
@@ -729,7 +699,6 @@ def build_scene_plan_host(
     mem_budget: int = 64 * 1024,
     order: str = "soar",
     soar_chunk: int = 512,
-    autotune=None,
     breakers=None,
 ) -> ScenePlan:
     """Host half of ``build_scene_plan``: all array leaves are numpy.
@@ -737,17 +706,14 @@ def build_scene_plan_host(
     This is the paper's offline pass (AdMAC metadata + SOAR reordering +
     SPADE selection + tile tables) with the device upload factored out —
     pair with ``upload_scene_plan``. Safe to call from planner threads.
-    ``autotune`` (a measured ``engine.autotune.CostTable``) overrides
-    adaptive-mode dispatch decisions with measured winners; see
-    ``build_plan_spec``. ``breakers`` (a ``backends.BreakerBoard``)
-    reroutes dispatch away from backends whose circuit breaker is open —
-    its repr (carrying the board generation) participates in plan-cache
-    keys, so routing changes rotate cached plans.
+    ``breakers`` (a ``backends.BreakerBoard``) reroutes dispatch away from
+    backends whose circuit breaker is open — its repr (carrying the board
+    generation) participates in plan-cache keys, so routing changes rotate
+    cached plans.
     """
     plan = _build_scene_plan(t, cfg, spec=spec, plan_tiles=plan_tiles,
                              mem_budget=mem_budget, order=order,
-                             soar_chunk=soar_chunk, autotune=autotune,
-                             breakers=breakers)
+                             soar_chunk=soar_chunk, breakers=breakers)
     return _map_leaves(plan, np.asarray)
 
 
@@ -760,7 +726,6 @@ def build_scene_plan(
     mem_budget: int = 64 * 1024,
     order: str = "soar",
     soar_chunk: int = 512,
-    autotune=None,
     breakers=None,
 ) -> ScenePlan:
     """One AdMAC + SOAR + SPADE pass -> a device-ready ScenePlan.
@@ -772,8 +737,7 @@ def build_scene_plan(
     """
     return upload_scene_plan(build_scene_plan_host(
         t, cfg, spec=spec, plan_tiles=plan_tiles, mem_budget=mem_budget,
-        order=order, soar_chunk=soar_chunk, autotune=autotune,
-        breakers=breakers))
+        order=order, soar_chunk=soar_chunk, breakers=breakers))
 
 
 def _build_scene_plan(
@@ -785,7 +749,6 @@ def _build_scene_plan(
     mem_budget: int = 64 * 1024,
     order: str = "soar",
     soar_chunk: int = 512,
-    autotune=None,
     breakers=None,
 ) -> ScenePlan:
     stem = stem_site(cfg)
@@ -799,8 +762,7 @@ def _build_scene_plan(
     offs3 = kernel_offsets(3)
     geometry = level_geometry(t, cfg)
     site_kw = dict(plan_tiles=plan_tiles, mem_budget=mem_budget, order=order,
-                   soar_chunk=soar_chunk, autotune=autotune,
-                   breakers=breakers)
+                   soar_chunk=soar_chunk, breakers=breakers)
     levels: list[LevelPlan] = []
     stats: list[dict] = []
     ordering0 = None
@@ -819,7 +781,7 @@ def _build_scene_plan(
 
             w = cfg.widths[li]
             sub, info, ordering = _assemble_site(
-                sub_coir, coords, mask, li, w, w, cfg.resolution,
+                sub_coir, coords, mask, li, w, w,
                 pinned=spec.levels[li] if spec is not None else None,
                 **site_kw)
         if li == 0:
@@ -835,7 +797,7 @@ def _build_scene_plan(
                                           kernel_offsets(size), res)
             # the stem's rows keep level 0's order (its 3^3 adjacency)
             stem_plan, info, _ = _assemble_site(
-                stem_coir, coords, mask, 0, c_in, c_out, cfg.resolution,
+                stem_coir, coords, mask, 0, c_in, c_out,
                 pinned=spec.stem if spec is not None else None,
                 ordering=ordering0, order_coir=levels[0].sub.coir, **site_kw)
         info["site"] = "stem"
@@ -851,14 +813,12 @@ def _assemble_site(
     li: int,
     c_in: int,
     c_out: int,
-    resolution: int,
     *,
     pinned: Dispatch | None,
     plan_tiles: bool,
     mem_budget: int,
     order: str,
     soar_chunk: int,
-    autotune=None,
     breakers=None,
     ordering=None,
     order_coir: COIR | None = None,
@@ -870,8 +830,8 @@ def _assemble_site(
     Rows are ordered from ``order_coir``'s adjacency (default: ``coir``)
     unless ``ordering`` is given.
 
-    Deterministic in ``(coir, coords, mask)`` for a fixed ``autotune``
-    table state — the streaming planner relies on this: running it on a
+    Deterministic in ``(coir, coords, mask)`` (and the breakers' routing)
+    — the streaming planner relies on this: running it on a
     patched (bitwise-equal) COIR yields bitwise-equal orderings, tiles and
     dispatch decisions.
     """
@@ -899,15 +859,6 @@ def _assemble_site(
                                         c_in, c_out, mem_budget)
             info["arf"] = float(attrs.arf_avg[0])
             info["da_elems"] = df.da_elems
-            if autotune is not None:
-                # measured-winner consult; a miss (recorded) keeps the
-                # analytical decision bitwise-unchanged
-                res3 = float(max(resolution >> li, 1)) ** 3
-                dispatch = autotune.adjust_dispatch(
-                    dispatch, n_in=n_active, n_out=n_active,
-                    c_in=c_in, c_out=c_out,
-                    density=n_active / res3, kernel_volume=k)
-                info["autotuned"] = dispatch.backend
             if dispatch.backend == SSPNNA and not _fits_vmem(
                     dispatch, c_in, c_out, k):
                 info["vmem_refused"] = True
@@ -1091,7 +1042,7 @@ class StreamPlanState:
             else:
                 w = self.cfg.widths[li]
                 sub, info, _ = _assemble_site(
-                    sub_coir, coords, mask, li, w, w, self.cfg.resolution,
+                    sub_coir, coords, mask, li, w, w,
                     pinned=(self.spec.levels[li] if self.spec is not None
                             else None),
                     plan_tiles=self.plan_tiles, mem_budget=self.mem_budget,

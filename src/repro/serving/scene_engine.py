@@ -244,9 +244,6 @@ class SceneEngine(ServingBase):
                           plan_tiles=family.spec_for(cap) is not None,
                           order=order, soar_chunk=soar_chunk)
                 for cap in family.capacities}
-            if getattr(ctx, "autotune", None) is not None:
-                for kw in self._bucket_kw.values():
-                    kw["autotune"] = ctx.autotune
             if self._breakers is not None:
                 for kw in self._bucket_kw.values():
                     kw["breakers"] = self._breakers
@@ -273,14 +270,9 @@ class SceneEngine(ServingBase):
         else:
             self._plan_kw = dict(spec=spec, plan_tiles=spec is not None,
                                  order=order, soar_chunk=soar_chunk)
-            if getattr(ctx, "autotune", None) is not None:
-                # the table's generation is repr'd into every cache key, so
-                # a measured-winner flip rotates keys (and the flip hook
-                # clears entries) — cached plans never outlive the decision
-                self._plan_kw["autotune"] = ctx.autotune
             if self._breakers is not None:
-                # same invariant for breaker routing: the board's repr
-                # carries its generation, so a trip/close rotates keys
+                # the board's repr carries its generation, so a trip/close
+                # rotates cache keys: cached plans never outlive the routing
                 self._plan_kw["breakers"] = self._breakers
             self._builder = None  # PlanCache default (build_scene_plan_host)
         self._streams: dict[str, StreamHandle] = {}
@@ -295,7 +287,6 @@ class SceneEngine(ServingBase):
             bucket_of=((lambda r: getattr(r, "_bucket", None))
                        if family is not None else None),
             on_shed=self._on_shed,
-            on_idle=self._make_idle_hook(ctx),
             faults=faults,
             on_wave_error=self._on_wave_error)
 
@@ -390,27 +381,6 @@ class SceneEngine(ServingBase):
         # stream's frame gate (the next planned frame rebuilds)
         if isinstance(req, StreamFrameRequest) and req.stream is not None:
             req.stream.state.skip_frame(req.frame_no)
-
-    def _make_idle_hook(self, ctx):
-        """Idle-gap re-profiling hook for the wave scheduler, or ``None``.
-
-        Only installed when the context carries a cost table *and* a
-        positive ``autotune_reprofile_ms`` budget — profiling never rides
-        the serving hot path, and tests (budget 0, the default) see no
-        hook at all.
-        """
-        table = getattr(ctx, "autotune", None)
-        budget_ms = float(getattr(ctx, "autotune_reprofile_ms", 0.0) or 0.0)
-        if table is None or budget_ms <= 0.0:
-            return None
-
-        def _idle(scheduler) -> None:
-            from repro.engine.autotune import reprofile
-
-            reprofile(table, registry=ctx.registry, ctx=ctx,
-                      budget_ms=budget_ms)
-
-        return _idle
 
     # -- admission -----------------------------------------------------------
 

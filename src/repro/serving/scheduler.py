@@ -231,7 +231,6 @@ class WaveScheduler:
         policy: AdmissionPolicy | None = None,
         bucket_of: Callable | None = None,
         on_shed: Callable | None = None,
-        on_idle: Callable | None = None,
         faults=None,
         on_wave_error: Callable | None = None,
     ):
@@ -251,12 +250,6 @@ class WaveScheduler:
         #: optional observer called on every shed request (e.g. the scene
         #: engine unblocks a stream whose frame was shed mid-sequence)
         self.on_shed = on_shed
-        #: optional idle-gap worker, called as ``on_idle(self)`` after a
-        #: ``run()`` drains the queue — strictly between ticks, never on
-        #: the serving hot path (the scene engine wires the autotune
-        #: re-profiler here when the context opts in with a budget)
-        self.on_idle = on_idle
-        self.idle_ticks = 0
         #: optional FaultInjector (serving.faults) exercising the plan /
         #: dispatch / slow-wave / worker-death seams; None = zero cost
         self.faults = faults
@@ -689,12 +682,7 @@ class WaveScheduler:
             max_waves: int | None = None) -> list:
         """Serve the queue (to empty, or at most ``max_waves`` admitted
         waves — the tick-driven mode arrival simulators use); returns the
-        completed-request list. Only one ``run`` may be active at a time.
-
-        When the queue drains completely, ``on_idle(self)`` (if set) runs
-        *after* the pipeline is done — the idle gap between ticks, where
-        background work (autotune re-profiling) can spend its budget
-        without touching a serving wave."""
+        completed-request list. Only one ``run`` may be active at a time."""
         if not self._idle.is_set():
             raise RuntimeError("run() already in progress on another thread")
         self._idle.clear()
@@ -707,9 +695,6 @@ class WaveScheduler:
         finally:
             self.running_sync = None
             self._idle.set()
-        if self.on_idle is not None and not self.queue:
-            self.idle_ticks += 1
-            self.on_idle(self)
         return self.completed
 
     def _timed_plan(self, req, wave: int):

@@ -160,14 +160,15 @@ def _extract(tmp_path, source, name="mod.py"):
 def test_c001_backward_acquisition(tmp_path):
     findings, graph = _extract(tmp_path, """
         from repro.analysis.runtime import ordered_lock
-        A = ordered_lock("autotune")
+        A = ordered_lock("faults.injector")
         B = ordered_lock("plan_cache")
         def f():
             with A:
                 with B:
                     pass
     """)
-    assert ("autotune", "plan_cache") in {(s, d) for s, d, _ in graph.edges}
+    assert ("faults.injector", "plan_cache") in \
+        {(s, d) for s, d, _ in graph.edges}
     assert "REPRO-C001" in _rules(findings)
 
 
@@ -175,7 +176,7 @@ def test_c001_via_call_closure(tmp_path):
     findings, graph = _extract(tmp_path, """
         from repro.analysis.runtime import ordered_lock
         A = ordered_lock("plan_cache")
-        B = ordered_lock("autotune")
+        B = ordered_lock("faults.injector")
         def inner():
             with B:
                 pass
@@ -184,7 +185,8 @@ def test_c001_via_call_closure(tmp_path):
                 inner()
     """)
     # forward in rank: edge extracted through the call graph, no finding
-    assert ("plan_cache", "autotune") in {(s, d) for s, d, _ in graph.edges}
+    assert ("plan_cache", "faults.injector") in \
+        {(s, d) for s, d, _ in graph.edges}
     assert findings == []
 
 
@@ -262,7 +264,7 @@ def test_repo_lock_graph_is_clean_and_live():
 
 def test_checked_lock_rejects_backward_acquire():
     lo = rt._CheckedLock("plan_cache")
-    hi = rt._CheckedLock("autotune")
+    hi = rt._CheckedLock("faults.injector")
     with hi:
         with pytest.raises(rt.LockOrderViolation):
             lo.acquire()
@@ -291,7 +293,7 @@ def test_checked_lock_same_rank_distinct_objects():
 
 
 def test_checked_lock_is_per_thread():
-    hi = rt._CheckedLock("autotune")
+    hi = rt._CheckedLock("faults.injector")
     lo = rt._CheckedLock("plan_cache")
     errs = []
 
@@ -476,17 +478,18 @@ def test_real_plan_corrupted_tables_are_caught(built):
 
 
 def test_p006_cache_keys_rotate(built):
-    from repro.engine.autotune import CostTable
+    from repro.engine.backends import BreakerBoard, default_registry
     from repro.engine.plan import PlanCache
     t, cfg, _ = built
     cache = PlanCache(capacity=t.capacity)
-    assert pc.check_cache_keys(cache, t, cfg, autotune=CostTable()) == []
+    assert pc.check_cache_keys(
+        cache, t, cfg, breakers=BreakerBoard(default_registry())) == []
 
     class Frozen:  # no generation counter at all
         def __repr__(self):
             return "Frozen()"
 
-    f = pc.check_cache_keys(cache, t, cfg, autotune=Frozen())
+    f = pc.check_cache_keys(cache, t, cfg, breakers=Frozen())
     assert any("no generation" in x.message for x in f)
 
     class Hidden:  # has a counter but a repr that does not mix it

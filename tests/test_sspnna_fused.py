@@ -327,55 +327,3 @@ def test_tile_arrays_carry_pair_counts_in_plans():
         n_out = cp.coir.mask.shape[0]
         assert int(jnp.min(tiles.in_rows)) >= 0          # DMA layout
         assert int(jnp.max(tiles.out_rows)) <= n_out     # trash row bound
-
-
-def test_block_n_autotune_pins_dispatch(rng):
-    """A tuner hook's block_n lands in Dispatch and the tuned engine path
-    stays numerically identical to the un-tuned one."""
-    from repro.data.scenes import N_CLASSES, make_scene
-    from repro.models.scn import UNetConfig, init_unet
-    from repro.sparse.tensor import SparseVoxelTensor
-
-    res, cap = 24, 2048
-    cfg = UNetConfig(widths=(8, 16), reps=1, resolution=res, capacity=cap,
-                     n_classes=N_CLASSES)
-
-    def load(seed):
-        coords, feats, _, mask = make_scene(seed, res, cap)
-        return SparseVoxelTensor(jnp.asarray(coords), jnp.asarray(feats),
-                                 jnp.asarray(mask))
-
-    seen = []
-
-    def tuner(c_in, n_out, d_o, d_i):
-        seen.append((c_in, n_out, d_o, d_i))
-        return 8  # deterministic pin; widths are multiples of 8
-
-    spec = engine.build_plan_spec([load(0)], cfg, mem_budget=16 * 1024,
-                                  tune_block_n=tuner)
-    tuned = [d for d in spec.levels if d.backend == engine.SSPNNA]
-    assert tuned and seen
-    assert all(d.block_n == 8 for d in tuned)
-
-    params = init_unet(jax.random.PRNGKey(0), cfg)
-    t = load(1)
-    plan = engine.build_scene_plan(t, cfg, spec=spec)
-    tuned_out = engine.apply_unet(params, t.feats, plan, backend="auto",
-                                  use_kernel=True)
-    # an un-tuned spec (block_n=0 -> full N) must give the same bits:
-    # block_n only re-tiles the N axis, never the K*C contraction
-    spec_plain = engine.build_plan_spec([load(0)], cfg, mem_budget=16 * 1024)
-    assert all(d.block_n == 0 for d in spec_plain.levels)
-    plan_plain = engine.build_scene_plan(t, cfg, spec=spec_plain)
-    plain_out = engine.apply_unet(params, t.feats, plan_plain, backend="auto",
-                                  use_kernel=True)
-    np.testing.assert_array_equal(np.asarray(tuned_out), np.asarray(plain_out))
-
-
-def test_autotune_block_n_returns_divisor():
-    from repro.engine.autotune import autotune_block_n
-
-    bn = autotune_block_n(8, 16, 8, 32, n_tiles=2, iters=1)
-    assert 16 % bn == 0 and bn >= 8
-    # memoized: second call is instant and identical
-    assert autotune_block_n(8, 16, 8, 32, n_tiles=2, iters=1) == bn
