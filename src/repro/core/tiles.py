@@ -5,11 +5,17 @@ sized by the SPADE plan: each tile owns a run of dO consecutive SOAR-ordered
 outputs, the tile's unique input rows (its L1 working set), and *tile-local*
 partner indices. Tiles whose unique-input count overshoots the RST
 allocation are split in two (next power of two), exactly the paper's
-overshoot rule. A *single row* whose working set overshoots ``delta_i`` is
-split across plane groups (unbudgeted mode) or is a hard planning error
-(budgeted mode) — pairs are never silently dropped; ``TilePlan`` carries
-the accounting (``n_row_splits`` / ``dropped_pairs``) so callers can assert
-the no-drop invariant.
+overshoot rule; the budgeted planner the serving engine uses instead closes
+a tile before the row that would overflow it. A *single row* whose working
+set overshoots ``delta_i`` is split across plane groups (unbudgeted mode)
+or is a hard planning error (budgeted mode) — pairs are never silently
+dropped; ``TilePlan`` carries the accounting (``n_row_splits`` /
+``dropped_pairs``) so callers can assert the no-drop invariant.
+
+Both planners work a window of ``dO`` rows at a time in numpy, never row
+by row: one sort of a run of windows' packed (window, partner, slot) pairs
+gives every window's working set at once, so the cost follows the active
+rows' pairs, and the same sorted pairs fill the tile tables.
 
 Host-side numpy; the result is a stack of fixed-shape arrays consumed by
 the Pallas kernel (``repro.kernels.sspnna``) and, via ``dma_tile_tables``,
@@ -24,6 +30,8 @@ from dataclasses import dataclass
 from typing import NamedTuple
 
 import numpy as np
+
+from repro.analysis import spans
 
 
 @dataclass
@@ -110,6 +118,31 @@ def _split_row_by_planes(part: np.ndarray, delta_i: int) -> list[np.ndarray]:
     return [np.asarray(g, np.int64) for g in groups]
 
 
+def _window_pairs(part: np.ndarray, start: int, stop: int,
+                  width: int) -> tuple[np.ndarray, ...]:
+    """The pairs of rows ``[start, stop)`` of ``part`` (COIR rows in walk
+    order), cut into windows of ``width`` rows from ``start``, from one sort
+    of packed ``(window, partner, slot)`` keys; ``slot`` is the row in the
+    window × K + the plane. Returns ``window``, ``partner``, ``slot`` and
+    ``head`` (the pair is its partner's first in the window: a window's
+    heads, in order, are its sorted working set)."""
+    k = part.shape[1]
+    flat = part[start:stop].ravel()
+    pos = np.flatnonzero(flat >= 0)
+    val_bits = int(flat.max(initial=1)).bit_length()
+    slot_bits = (width * k - 1).bit_length()
+    assert ((stop - start) // width).bit_length() + val_bits + slot_bits < 64
+    key = flat[pos].astype(np.int64) << slot_bits
+    key += (pos // (width * k)) << (val_bits + slot_bits)
+    key += pos % (width * k)
+    key.sort()
+    slot = key & ((1 << slot_bits) - 1)
+    key >>= slot_bits
+    head = np.ones(len(key), bool)
+    head[1:] = key[1:] != key[:-1]
+    return key >> val_bits, key & ((1 << val_bits) - 1), slot, head
+
+
 def build_tile_plan(
     cirf_indices: np.ndarray,
     order: np.ndarray,
@@ -134,80 +167,141 @@ def build_tile_plan(
     groups into several tiles that share the output row; such plans require
     an accumulating scatter (``TilePlan.n_row_splits > 0`` flags them, and
     the fused kernel's overwrite-DMA path refuses them).
+
+    The budgeted walk assumes that every tile from where it stands fills
+    by rows: it sorts the pairs of the next ``delta_o``-row windows, and
+    each window up to the first whose working set overflows ``delta_i`` is
+    a tile. That window closes at the last row whose running count of
+    first-seen partners fits, and the walk goes on after it, sorting half
+    as many windows (twice as many after a pass with no overflow). The
+    unbudgeted mode sorts its fixed windows once and halves only those that
+    overflow. A tile's sorted pairs fill its row: the first pair of each
+    partner gives ``in_rows``, and a pair's ``local_idx`` is that first
+    pair's rank. Adds ``rows``, ``tiles`` and ``input_closed`` (the tiles
+    that ``delta_i`` closed short of ``delta_o`` rows) to the innermost
+    span's counters.
     """
     cirf_indices = np.asarray(cirf_indices)
     k = cirf_indices.shape[1]
+    order = np.asarray(order, np.int64)
+    if n_tiles is not None and delta_i < k:
+        raise ValueError(f"delta_i {delta_i} < kernel volume {k}")
+    part = cirf_indices[order]          # (n, K): the COIR rows in walk order
+    n = len(order)
 
-    # each planned tile: (rows, planes) — planes is None for "all K planes"
-    tiles: list[tuple[np.ndarray, np.ndarray | None]] = []
-    n_row_splits = 0
+    starts: list[int] = []    # each tile's first position in ``order``
+    lengths: list[int] = []   # and its rows
+    chunks: list[tuple] = []  # (offset, window, partner, slot): the sorted
+    #                           pairs of tiles offset + window
+    n_row_splits = input_closed = 0
+
+    def take(start, stop, win, partner, slot, w0, w1):
+        """Windows ``[w0, w1)`` of ``win`` (cut from ``start``) as tiles."""
+        lo, hi = np.searchsorted(win, (w0, w1))
+        chunks.append((len(starts) - w0, win[lo:hi], partner[lo:hi],
+                       slot[lo:hi]))
+        firsts = range(start + w0 * delta_o,
+                       min(stop, start + w1 * delta_o), delta_o)
+        starts.extend(firsts)
+        lengths.extend(min(delta_o, stop - a) for a in firsts)
 
     if n_tiles is not None:
-        if delta_i < k:
-            raise ValueError(f"delta_i {delta_i} < kernel volume {k}")
-        cur: list[int] = []
-        cur_uniq: set[int] = set()
-        for r in np.asarray(order, np.int64):
-            part = cirf_indices[r]
-            new = set(part[part >= 0].tolist())
-            if len(new) > delta_i:  # can't happen while delta_i >= K; be loud
+        s, reach = 0, -(-n // delta_o)   # reach: the windows a pass sorts
+        while s < n:
+            stop = min(n, s + reach * delta_o)
+            win, partner, slot, head = _window_pairs(part, s, stop, delta_o)
+            n_win = -(-(stop - s) // delta_o)
+            fits = np.bincount(win[head], minlength=n_win) <= delta_i
+            ok = n_win if fits.all() else int(np.argmin(fits))
+            take(s, stop, win, partner, slot, 0, ok)
+            if ok == n_win:
+                s, reach = stop, 2 * reach
+                continue
+            # window ``ok`` overflows: its tile closes before the first row
+            # whose first-seen partners take the working set past delta_i
+            b = s + ok * delta_o
+            lo, hi = np.searchsorted(win, (ok, ok + 1))
+            rows = slot[lo:hi] // k
+            new = np.bincount(rows[head[lo:hi]], minlength=delta_o)
+            fit = int(np.count_nonzero(np.cumsum(new) <= delta_i))
+            if fit == 0:  # can't happen while delta_i >= K; be loud
                 raise ValueError(
-                    f"row {int(r)} working set {len(new)} > delta_i {delta_i} "
-                    "in budgeted mode (would drop pairs)")
-            if cur and (len(cur) == delta_o or len(cur_uniq | new) > delta_i):
-                tiles.append((np.asarray(cur, np.int64), None))
-                cur, cur_uniq = [], set()
-            cur.append(int(r))
-            cur_uniq |= new
-        if cur:
-            tiles.append((np.asarray(cur, np.int64), None))
-        if len(tiles) > n_tiles:
+                    f"row {int(order[b])} working set {int(new[0])} > "
+                    f"delta_i {delta_i} in budgeted mode (would drop pairs)")
+            keep = lo + np.flatnonzero(rows < fit)
+            chunks.append((len(starts) - ok, win[keep], partner[keep],
+                           slot[keep]))
+            starts.append(b)
+            lengths.append(fit)
+            input_closed += 1
+            s, reach = b + fit, max(reach // 2, 2 * ok, 1)
+        if len(starts) > n_tiles:
             raise ValueError(
-                f"scene needs {len(tiles)} tiles > budget {n_tiles} "
+                f"scene needs {len(starts)} tiles > budget {n_tiles} "
                 f"(delta_o={delta_o}, delta_i={delta_i})")
     else:
-        def emit(rows: np.ndarray):
-            """Split until the unique-input working set fits delta_i."""
-            part = cirf_indices[rows]
-            uniq = np.unique(part[part >= 0])
-            if len(uniq) > delta_i:
-                if len(rows) > 1:
-                    mid = len(rows) // 2
-                    emit(rows[:mid])
-                    emit(rows[mid:])
-                else:  # single-row overshoot: split across plane groups
-                    nonlocal n_row_splits
-                    groups = _split_row_by_planes(part[0], delta_i)
-                    n_row_splits += len(groups) - 1
-                    for g in groups:
-                        tiles.append((rows, g))
-            else:
-                tiles.append((rows, None))
+        def emit(a: int, b: int):
+            """Split rows ``[a, b)`` until the working set fits delta_i."""
+            nonlocal n_row_splits, input_closed
+            win, partner, slot, head = _window_pairs(part, a, b, b - a)
+            if np.count_nonzero(head) <= delta_i:
+                chunks.append((len(starts), win, partner, slot))
+                starts.append(a)
+                lengths.append(b - a)
+            elif b - a > 1:
+                input_closed += 1
+                emit(a, a + (b - a) // 2)
+                emit(a + (b - a) // 2, b)
+            else:  # single-row overshoot: split across plane groups
+                groups = _split_row_by_planes(part[a], delta_i)
+                n_row_splits += len(groups) - 1
+                input_closed += len(groups) - 1
+                for g in groups:
+                    keep = np.isin(slot, g)
+                    chunks.append((len(starts), win[keep], partner[keep],
+                                   slot[keep]))
+                    starts.append(a)
+                    lengths.append(1)
 
-        for s in range(0, len(order), delta_o):
-            emit(np.asarray(order[s:s + delta_o], np.int64))
+        win, partner, slot, head = _window_pairs(part, 0, n, delta_o)
+        n_win = -(-n // delta_o)
+        over = np.bincount(win[head], minlength=n_win) > delta_i
+        w0 = 0
+        for w in [*np.flatnonzero(over).tolist(), n_win]:
+            take(0, n, win, partner, slot, w0, w)
+            if w < n_win:
+                emit(w * delta_o, min(n, (w + 1) * delta_o))
+            w0 = w + 1
 
-    t = n_tiles if n_tiles is not None else len(tiles)
+    t = n_tiles if n_tiles is not None else len(starts)
     out_rows = np.full((t, delta_o), -1, np.int32)
     in_rows = np.full((t, delta_i), -1, np.int32)
     local_idx = np.full((t, delta_o, k), -1, np.int32)
     pair_counts = np.zeros((t,), np.int32)
-    for ti, (rows, planes) in enumerate(tiles):
-        out_rows[ti, : len(rows)] = rows
-        part = cirf_indices[rows].copy()  # (r, K)
-        if planes is not None:  # plane-split tile: hole the other planes
-            keep = np.zeros((k,), bool)
-            keep[planes] = True
-            part[:, ~keep] = -1
-        valid = part >= 0
-        uniq = np.unique(part[valid])
-        assert len(uniq) <= delta_i, "planner invariant: working set fits"
-        in_rows[ti, : len(uniq)] = uniq
-        loc = np.searchsorted(uniq, part)
-        loc = np.clip(loc, 0, max(len(uniq) - 1, 0))
-        hit = valid & (uniq[loc] == part) if len(uniq) else np.zeros_like(valid)
-        local_idx[ti, : len(rows)] = np.where(hit, loc, -1)
-        pair_counts[ti] = int(hit.sum())
+    if starts:
+        lens = np.asarray(lengths)
+        tile_of = np.repeat(np.arange(len(lens)), lens)
+        at = np.arange(len(tile_of)) - np.repeat(np.cumsum(lens) - lens, lens)
+        out_rows[tile_of, at] = order[np.asarray(starts)[tile_of] + at]
+        tile = np.concatenate([t0 + w for t0, w, _, _ in chunks])
+        partner = np.concatenate([c[2] for c in chunks])
+        slot = np.concatenate([c[3] for c in chunks])
+        # a tile's pairs are one run sorted by partner: its heads (a
+        # partner's first pair) are its working set, in order, and a pair's
+        # local index is its head's rank in the tile
+        first = np.ones(len(tile), bool)
+        first[1:] = tile[1:] != tile[:-1]
+        head = first.copy()
+        head[1:] |= partner[1:] != partner[:-1]
+        h = np.flatnonzero(head)
+        rank = np.arange(len(h))
+        rank -= np.maximum.accumulate(np.where(first[h], rank, 0))
+        assert rank.max(initial=-1) < delta_i, \
+            "planner invariant: working set fits"
+        in_rows[tile[h], rank] = partner[h]
+        local_idx.reshape(t, -1)[tile, slot] = rank[np.cumsum(head) - 1]
+        pair_counts[: len(lens)] = np.bincount(tile, minlength=len(lens))
+    spans.count(rows=n, tiles=len(starts), input_closed=input_closed)
     return TilePlan(out_rows, in_rows, local_idx, pair_counts,
                     n_row_splits=n_row_splits, dropped_pairs=0)
 
